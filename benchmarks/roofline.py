@@ -22,7 +22,7 @@ import os
 from typing import Dict, List, Optional
 
 from repro.configs import SHAPES, get_config
-from repro.launch.mesh import HW
+from repro.launch.mesh import PRODUCTION_KIND, hw_peaks
 from repro.models.registry import build_model
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts", "dryrun")
@@ -181,9 +181,10 @@ def analyze_cell(cell: dict) -> Optional[dict]:
                 + coll.get("reduce-scatter", 0.0)
                 + coll.get("all-to-all", 0.0)
                 + coll.get("collective-permute", 0.0))
-    compute_s = flops_dev / HW["peak_flops_bf16"]
-    memory_s = mem_dev / HW["hbm_bw"]
-    collective_s = coll_dev / HW["ici_bw"]
+    hw = hw_peaks(PRODUCTION_KIND)
+    compute_s = flops_dev / hw["peak_flops_bf16"]
+    memory_s = mem_dev / hw["hbm_bw"]
+    collective_s = coll_dev / hw["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -191,7 +192,7 @@ def analyze_cell(cell: dict) -> Optional[dict]:
     model_flops = rl["model_flops_total"]
     useful = model_flops / max(flops_dev * n_chips, 1e-9)
     # roofline fraction: useful work at peak over the modelled step time
-    frac = (model_flops / n_chips / HW["peak_flops_bf16"]) / max(bound, 1e-12)
+    frac = (model_flops / n_chips / hw["peak_flops_bf16"]) / max(bound, 1e-12)
     return {
         "arch": arch, "shape": shape_name, "mesh": cell["mesh"],
         "rules": cell.get("rules"), "grad_accum": cell.get("grad_accum", 1),

@@ -82,7 +82,24 @@ class Router:
         shared-memory array transport (launch/shm_transport.py): None
         auto-enables it where the host supports it, False forces the
         pickle path. ``shm_threshold`` overrides the per-array size above
-        which arrays ride shm (default: the measured crossover)."""
+        which arrays ride shm (default: the measured crossover).
+
+        The process plane is CPU-only: an accelerator chip belongs to one
+        process, and this parent already holds every chip it can see. On
+        an accelerator, the in-process plane drives all chips (one mesh
+        slice per group) from this one process."""
+        # the device plane leases each group a disjoint mesh slice; on one
+        # default device every group shares the lone slice (legacy view)
+        self.device_plane = device_plane or DevicePlane(
+            slice_size=devices_per_group)
+        if process_plane:
+            platforms = {d.platform for d in self.device_plane.devices()}
+            if platforms != {"cpu"}:
+                raise ValueError(
+                    f"process_plane=True needs CPU devices, found "
+                    f"{sorted(platforms)}: a chip belongs to one process. "
+                    "Use the in-process plane (process_plane=False), which "
+                    "drives every chip from this process.")
         self.now = now
         self.process_plane = process_plane
         self.proc_wpg_factory = proc_wpg_factory
@@ -96,10 +113,6 @@ class Router:
         self.deployments: Dict[str, api.DeploymentSpec] = {}
         self.group_of: Dict[str, int] = {}       # deployment -> node group
         self.state_managers: Dict[int, StateManager] = {}
-        # the device plane leases each group a disjoint mesh slice; on one
-        # default device every group shares the lone slice (legacy view)
-        self.device_plane = device_plane or DevicePlane(
-            slice_size=devices_per_group)
         # realized migration costs (reshard included), consumed by the
         # PlacementDirector to calibrate the migration-cost floor
         self.migrate_log: List[dict] = []

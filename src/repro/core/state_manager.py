@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import logging
 import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -35,6 +36,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models import common
+
+logger = logging.getLogger(__name__)
 
 
 class Tier(enum.IntEnum):
@@ -61,6 +64,20 @@ class Entry:
 
 def _nbytes(x) -> int:
     return int(np.prod(x.shape)) * x.dtype.itemsize
+
+
+def _spec_divides(shape, spec, mesh) -> bool:
+    """Whether every dim of ``shape`` divides by the product of the mesh
+    axes ``spec`` assigns to it (what a sharded ``device_put`` requires)."""
+    if len(spec) > len(shape):
+        return False
+    for dim, axes in zip(shape, spec):
+        if axes is None:
+            continue
+        names = (axes,) if isinstance(axes, str) else tuple(axes)
+        if dim % int(np.prod([mesh.shape[a] for a in names])):
+            return False
+    return True
 
 
 class StateManager:
@@ -112,19 +129,21 @@ class StateManager:
 
     def _to_device(self, arr, spec=None):
         """Place a host array onto THIS node's mesh slice, restoring
-        ``spec`` when it still fits the slice's mesh; falls back to a
-        replicated put on the slice (or the default device with no slice)."""
+        ``spec`` when its dims divide over the slice's mesh; a spec that
+        does not divide there is logged and the leaf replicated on the
+        slice (or put on the default device with no slice)."""
         if self.mesh_slice is not None:
+            mesh = self.mesh_slice.mesh
             if spec is not None:
-                try:
-                    return jax.device_put(
-                        arr, NamedSharding(self.mesh_slice.mesh, spec))
-                except Exception:  # noqa: BLE001 - spec may not divide here
-                    pass
+                if _spec_divides(arr.shape, spec, mesh):
+                    return jax.device_put(arr, NamedSharding(mesh, spec))
+                logger.warning(
+                    "%s: spec %s does not divide shape %s over mesh %s; "
+                    "replicating on the slice", self.node_id, spec,
+                    tuple(arr.shape), dict(mesh.shape))
             # replicate across the slice: compatible under jit with leaves
             # that DID reshard onto the slice's mesh
-            return jax.device_put(
-                arr, NamedSharding(self.mesh_slice.mesh, PartitionSpec()))
+            return jax.device_put(arr, NamedSharding(mesh, PartitionSpec()))
         return jnp.asarray(arr)
 
     # ----------------------------------------------------------- register

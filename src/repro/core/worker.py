@@ -86,6 +86,25 @@ def _value_readout(logits):
     return jnp.tanh(v / jnp.sqrt(logits.shape[-1] * 1.0))
 
 
+def jit_update_actor(model: Model, grpo_cfg: grpo.GRPOConfig,
+                     adamw_cfg: opt.AdamWConfig):
+    """The UPDATE_ACTOR primitive as one compiled program:
+    ``(params, opt_state, batch) -> (TrainState, metrics)``.
+
+    The optimizer moments are the bulk of the train state (f32, twice the
+    bf16 params) and only their own deployment reads them, so they are
+    donated: the new moments reuse their buffers and an update holds one
+    copy of them on the device, not two. Params are not donated, since a
+    weight sync may share their arrays with another deployment."""
+    step = grpo.make_update_actor(model, grpo_cfg, adamw_cfg)
+
+    def _update(params, opt_state, batch):
+        return step(TrainState(params, opt_state, jnp.asarray(0, jnp.int32)),
+                    batch)
+
+    return jax.jit(_update, donate_argnums=1)
+
+
 class WorkerProcessGroup:
     def __init__(self, spec: api.DeploymentSpec, state_manager: StateManager,
                  rng_seed: int = 0, grpo_cfg: Optional[grpo.GRPOConfig] = None,
@@ -309,12 +328,10 @@ class WorkerProcessGroup:
 
     def _op_update_actor(self, batch):
         if self._update_actor is None:
-            self._update_actor = jax.jit(grpo.make_update_actor(
-                self.model, self.grpo_cfg, self.adamw_cfg))
-        params = self.params()
-        state = TrainState(params, self.opt_state(),
-                           jnp.asarray(0, jnp.int32))
-        new_state, metrics = self._update_actor(state, batch)
+            self._update_actor = jit_update_actor(self.model, self.grpo_cfg,
+                                                  self.adamw_cfg)
+        new_state, metrics = self._update_actor(self.params(),
+                                                self.opt_state(), batch)
         self._store(params=new_state.params, opt_state=new_state.opt_state)
         return {k: float(v) for k, v in metrics.items()}
 

@@ -6,6 +6,9 @@ state in VMEM scratch across the (sequential) kv_blocks dim. The GQA group
 is processed natively — the cache is read once, NOT repeated, which is the
 point of GQA at decode time (HBM-bandwidth-bound).
 
+The cache is head-major (B, K, T, D), so a block (1, 1, block_k, D) keeps
+the size-1 kv-head dim out of the two dims the TPU compiler tiles.
+
 The current decode position arrives as a scalar-prefetch operand (SMEM) so
 cache slots beyond ``pos`` are masked without host-side slicing.
 """
@@ -38,8 +41,8 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     @pl.when(k_start <= pos)
     def _compute():
         q = q_ref[0, 0, :, :]                     # (g, d) query-head group
-        k = k_ref[0, :, 0, :]                     # (bk, d)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                           # (bk, d)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (g, bk)
@@ -66,10 +69,10 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 def decode_attention(q, k_cache, v_cache, pos, *,
                      scale: Optional[float] = None, block_k: int = 256,
                      interpret: bool = False):
-    """q: (B, H, D) one new token's queries; k/v_cache: (B, T, K, D);
+    """q: (B, H, D) one new token's queries; k/v_cache: (B, K, T, D);
     pos: scalar int32 (attend to cache[: pos+1]). Returns (B, H, D)."""
     b, h, d = q.shape
-    t, kh = k_cache.shape[1], k_cache.shape[2]
+    kh, t = k_cache.shape[1], k_cache.shape[2]
     assert h % kh == 0
     g = h // kh
     assert t % block_k == 0, (t, block_k)
@@ -87,10 +90,10 @@ def decode_attention(q, k_cache, v_cache, pos, *,
             grid=(b, kh, nk),
             in_specs=[
                 pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik, pos: (ib, ih, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ih, ik, pos: (ib, ik, ih, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ih, ik, pos: (ib, ik, ih, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda ib, ih, ik, pos: (ib, ih, ik, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda ib, ih, ik, pos: (ib, ih, ik, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d),
                                    lambda ib, ih, ik, pos: (ib, ih, 0, 0)),

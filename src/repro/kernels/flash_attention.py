@@ -1,7 +1,10 @@
 """Pallas TPU flash-attention forward kernel.
 
 Online-softmax attention with explicit BlockSpec VMEM tiling: the (S, T)
-score matrix never leaves VMEM. Grid = (batch, heads, q_blocks, kv_blocks);
+score matrix never leaves VMEM. Operands are head-major (B, H, S, D): the
+TPU compiler tiles the last two block dims by (8, 128) or the full extent,
+so the per-head block (1, 1, block_q, D) keeps the size-1 heads dim out of
+the tiled pair. Grid = (batch, heads, q_blocks, kv_blocks);
 TPU grids execute the trailing dim sequentially, so the running max / sum /
 accumulator live in VMEM scratch across kv iterations. Causal and
 sliding-window blocks that are fully masked are skipped with ``pl.when``
@@ -50,9 +53,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(relevant)
     def _compute():
-        q = q_ref[0, :, 0, :]                      # (bq, d)
-        k = k_ref[0, :, 0, :]                      # (bk, d)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]                            # (bq, d)
+        k = k_ref[0, 0]                            # (bk, d)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -82,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _write():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
@@ -90,10 +93,10 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         scale: Optional[float] = None,
                         block_q: int = 128, block_k: int = 128,
                         interpret: bool = False):
-    """q: (B, S, H, D); k, v: (B, T, H, D) (KV pre-repeated for GQA)."""
-    b, s, h, d = q.shape
-    t = k.shape[1]
-    assert k.shape == (b, t, h, d) and v.shape == (b, t, h, d)
+    """q: (B, H, S, D); k, v: (B, H, T, D) (KV pre-repeated for GQA)."""
+    b, h, s, d = q.shape
+    t = k.shape[2]
+    assert k.shape == (b, h, t, d) and v.shape == (b, h, t, d)
     assert s % block_q == 0 and t % block_k == 0, (s, t, block_q, block_k)
     scale = d ** -0.5 if scale is None else scale
     nq, nk = s // block_q, t // block_k
@@ -106,13 +109,13 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, iq, ik: (ib, ik, ih, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda ib, ih, iq, ik: (ib, ik, ih, 0)),
+            pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, d),
+                               lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),

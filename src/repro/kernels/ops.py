@@ -1,9 +1,10 @@
-"""Jit'd wrappers around the Pallas kernels.
+"""Wrappers around the Pallas kernels.
 
-Interpret mode is selected automatically off-TPU (the CPU container runs the
-kernel bodies in Python for correctness validation); on TPU the compiled
-kernels run natively. Wrappers handle padding to block multiples and the
-GQA repeat for the flash path.
+On TPU the kernels compile and run natively; on the CPU backend they run in
+interpret mode (the kernel bodies execute as jnp for correctness tests);
+any other platform is an error. Wrappers take the models' (B, S, H, D)
+layout, transpose to the kernels' head-major layout, and handle padding to
+block multiples and the GQA repeat for the flash path.
 """
 from __future__ import annotations
 
@@ -19,7 +20,12 @@ from repro.kernels import ssd as ssd_k
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels run natively on TPU or interpreted on CPU; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -45,10 +51,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         # if padding stays beyond every query position, which holds since
         # pads sit at kv positions >= t > any valid causal query position.
         assert causal or pad_k == 0, "non-causal padding needs a kv mask"
+    hm = lambda a: jnp.swapaxes(a, 1, 2)        # (B,S,H,D) <-> (B,H,S,D)
     out = fa.flash_attention_fwd(
-        q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
-        block_q=bq, block_k=bk, interpret=_interpret())
-    return out[:, :s]
+        hm(q), hm(k), hm(v), causal=causal, window=window, softcap=softcap,
+        scale=scale, block_q=bq, block_k=bk, interpret=_interpret())
+    return hm(out)[:, :s]
 
 
 def decode_attention(q, k_cache, v_cache, pos, *,
@@ -59,8 +66,9 @@ def decode_attention(q, k_cache, v_cache, pos, *,
         pad = (-t) % bk
         k_cache = jnp.pad(k_cache, [(0, 0), (0, pad), (0, 0), (0, 0)])
         v_cache = jnp.pad(v_cache, [(0, 0), (0, pad), (0, 0), (0, 0)])
-    return dec.decode_attention(q, k_cache, v_cache, pos, scale=scale,
-                                block_k=bk, interpret=_interpret())
+    return dec.decode_attention(
+        q, jnp.swapaxes(k_cache, 1, 2), jnp.swapaxes(v_cache, 1, 2), pos,
+        scale=scale, block_k=bk, interpret=_interpret())
 
 
 def ssd(x, dt, A, B, C, *, chunk: int = 256):
@@ -74,6 +82,8 @@ def ssd(x, dt, A, B, C, *, chunk: int = 256):
         dt = jnp.pad(dt, [(0, 0), (0, pad), (0, 0)])
         B = jnp.pad(B, [(0, 0), (0, pad), (0, 0), (0, 0)])
         C = jnp.pad(C, [(0, 0), (0, pad), (0, 0), (0, 0)])
-    y, state = ssd_k.ssd_chunk_scan(x, dt, A, B, C, chunk=ck,
-                                    interpret=_interpret())
-    return y[:, :s], state
+    b, sp, h, _ = x.shape
+    y, state = ssd_k.ssd_chunk_scan(
+        jnp.swapaxes(x, 1, 2), jnp.swapaxes(dt, 1, 2).reshape(b, h, 1, sp),
+        A, B[:, :, 0], C[:, :, 0], chunk=ck, interpret=_interpret())
+    return jnp.swapaxes(y, 1, 2)[:, :s], state

@@ -14,6 +14,13 @@ Per (b, h, c) program:
 
 The final state per (b, h) is emitted for prefill seeding. ngroups == 1
 (B/C shared across heads), matching the assigned mamba2/zamba2 configs.
+
+TPU layout: the TPU compiler tiles the last two block dims by (8, 128) or
+their full extent, so x/y are head-major (b, h, s, p), dt arrives as a
+(b, h, 1, s) row per head, B/C drop their size-1 group dim, and A is read
+from SMEM. The body is written over 2-D tiles only: the in-chunk prefix sum
+is a matmul against an upper-triangular ones matrix, and the column views
+of per-position vectors come from transposing their broadcast.
 """
 from __future__ import annotations
 
@@ -25,70 +32,75 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_out_ref,
                 s_ref, *, chunk: int, nc: int):
+    ih = pl.program_id(1)
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    x = x_ref[0, :, 0, :]                       # (l, p)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)    # (l,)
-    a = a_ref[0]                                # scalar A (negative)
-    bmat = b_ref[0, :, 0, :].astype(jnp.float32)  # (l, n)
-    cmat = c_ref[0, :, 0, :].astype(jnp.float32)  # (l, n)
+    x = x_ref[0, 0].astype(jnp.float32)         # (l, p)
+    dt = dt_ref[0, 0].astype(jnp.float32)       # (1, l) row
+    a = a_ref[ih]                               # scalar A (negative)
+    bmat = b_ref[0].astype(jnp.float32)         # (l, n)
+    cmat = c_ref[0].astype(jnp.float32)         # (l, n)
 
-    dA = dt * a                                 # (l,)
-    dA_cs = jnp.cumsum(dA)                      # (l,)
-    # segsum: T[i, j] = sum_{j<k<=i} dA_k, lower-triangular
-    seg = dA_cs[:, None] - dA_cs[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tril = ii >= jj
-    decay = jnp.where(tril, jnp.exp(seg), 0.0)  # (l, l)
+    # inclusive prefix sum of dA along the chunk: cs[i] = sum_{j<=i} dA[j]
+    dA_cs = _dot(dt * a, (ii <= jj).astype(jnp.float32),
+                 ((1,), (0,)))                  # (1, l)
+    cs_rows = jnp.broadcast_to(dA_cs, (chunk, chunk))   # [i, j] = cs[j]
+    cs_cols = cs_rows.T                                 # [i, j] = cs[i]
+    dt_col = jnp.broadcast_to(dt, (chunk, chunk)).T[:, :1]   # (l, 1)
+    # segsum: T[i, j] = sum_{j<k<=i} dA_k, lower-triangular
+    decay = jnp.where(ii >= jj, jnp.exp(cs_cols - cs_rows), 0.0)  # (l, l)
 
-    scores = jax.lax.dot_general(
-        cmat, bmat, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)     # (l, l)
-    scores = scores * decay * dt[None, :]
+    scores = _dot(cmat, bmat, ((1,), (1,)))     # (l, l)
+    scores = scores * decay * dt
     # Accumulate the whole y path in f32: downcasting `scores` to bf16 here
     # loses ~2^-8 relative on each large intermediate term, and the intra-
     # chunk + carried-state contributions cancel, so small outputs absorb
     # absolute error far above the final-cast quantisation (observed 0.18
     # max-abs on |y|~0.03 elements at s=96, chunk=32). The ONLY bf16
     # rounding left is the single y_ref store below.
-    y = jax.lax.dot_general(
-        scores, x.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)     # (l, p)
+    y = _dot(scores, x, ((1,), (0,)))           # (l, p)
 
     # off-diagonal: contribution of the incoming state S (p, n)
     s_in = s_ref[...]                           # (p, n) f32
-    c_proj = jax.lax.dot_general(
-        cmat, s_in, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)     # (l, p)
-    y = y + jnp.exp(dA_cs)[:, None] * c_proj
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    c_proj = _dot(cmat, s_in, ((1,), (1,)))     # (l, p)
+    y = y + jnp.exp(cs_cols[:, :1]) * c_proj
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update
-    chunk_decay = jnp.exp(dA_cs[-1])
-    w = jnp.exp(dA_cs[-1] - dA_cs) * dt         # (l,)
-    upd = jax.lax.dot_general(
-        x.astype(jnp.float32), bmat * w[:, None],
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)     # (p, n)
-    s_ref[...] = s_in * chunk_decay + upd
+    cs_last = dA_cs[:, chunk - 1:]              # (1, 1)
+    w = jnp.exp(cs_last - cs_cols[:, :1]) * dt_col   # (l, 1)
+    upd = _dot(x, bmat * w, ((0,), (0,)))       # (p, n)
+    # (p, 1) column of cs[l-1]: Mosaic broadcasts one tiled dim at a time
+    p_dim = s_in.shape[0]
+    cs_last_col = jnp.broadcast_to(dA_cs, (p_dim, chunk))[:, chunk - 1:]
+    s_ref[...] = s_in * jnp.exp(cs_last_col) + upd
 
     @pl.when(ic == nc - 1)
     def _emit():
-        state_out_ref[0, 0, :, :] = s_ref[...]
+        state_out_ref[0, 0] = s_ref[...]
 
 
 def ssd_chunk_scan(x, dt, A, B, C, *, chunk: int = 256,
                    interpret: bool = False):
-    """x: (b, s, h, p); dt: (b, s, h) post-softplus; A: (h,) negative;
-    B, C: (b, s, 1, n) (ngroups=1). Returns (y (b,s,h,p), state (b,h,p,n))."""
-    b, s, h, p = x.shape
+    """x: (b, h, s, p); dt: (b, h, 1, s) post-softplus; A: (h,) negative;
+    B, C: (b, s, n) (ngroups=1). Returns (y (b,h,s,p), state (b,h,p,n))."""
+    b, h, s, p = x.shape
     n = B.shape[-1]
     assert s % chunk == 0, (s, chunk)
     nc = s // chunk
@@ -98,21 +110,21 @@ def ssd_chunk_scan(x, dt, A, B, C, *, chunk: int = 256,
         kernel,
         grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda ib, ih, ic: (ib, ic, ih)),
-            pl.BlockSpec((1,), lambda ib, ih, ic: (ih,)),
-            pl.BlockSpec((1, chunk, 1, n), lambda ib, ih, ic: (ib, ic, 0, 0)),
-            pl.BlockSpec((1, chunk, 1, n), lambda ib, ih, ic: (ib, ic, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda ib, ih, ic: (ib, ih, 0, ic)),
+            pl.BlockSpec((1, chunk, n), lambda ib, ih, ic: (ib, ic, 0)),
+            pl.BlockSpec((1, chunk, n), lambda ib, ih, ic: (ib, ic, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
             pl.BlockSpec((1, 1, p, n), lambda ib, ih, ic: (ib, ih, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
+            jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A.astype(jnp.float32), B, C)
+    )(A.astype(jnp.float32), x, dt, B, C)
     return y, state

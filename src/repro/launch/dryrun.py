@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, SHAPES, ShapeSpec, get_config, shape_applicable
 from repro.launch import hlo_cost
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import PRODUCTION_KIND, hw_peaks, make_production_mesh
 from repro.models import sharding as shd
 from repro.models.layers import Ctx
 from repro.models.registry import build_model
@@ -303,9 +303,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     mult = {"train": 6, "prefill": 2, "decode": 2}[shape.kind]
     model_flops = mult * n_active * tokens
 
-    compute_s = flops / HW["peak_flops_bf16"]
-    memory_s = bytes_acc / HW["hbm_bw"]
-    collective_s = coll_bytes / HW["ici_bw"]
+    hw = hw_peaks(PRODUCTION_KIND)
+    compute_s = flops / hw["peak_flops_bf16"]
+    memory_s = bytes_acc / hw["hbm_bw"]
+    collective_s = coll_bytes / hw["ici_bw"]
     dominant = max(
         (("compute", compute_s), ("memory", memory_s),
          ("collective", collective_s)), key=lambda kv: kv[1])[0]

@@ -39,6 +39,11 @@ XLA_HINT = ("set XLA_FLAGS=--xla_force_host_platform_device_count=N "
             "before importing jax to get N virtual CPU devices")
 
 
+# device kind of the v5e pod (16x16 chips per pod) the production mesh
+# describes; the dry-run and roofline read its peaks through hw_peaks
+PRODUCTION_KIND = "TPU v5 lite"
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -81,19 +86,16 @@ class MeshSlice:
 
 
 def env_for_slice(sl: MeshSlice) -> Dict[str, str]:
-    """The child-process environment that makes a spawned group process see
-    EXACTLY its slice's devices. On the CPU backend there is no per-device
-    visibility mask, so the child gets its own virtual-device world of the
-    slice's size (slice identity is positional there — fine, since CPU
-    devices are fungible). On real accelerators, visibility masking means
-    the child's ``jax.devices()`` IS the slice. Must be applied in the
-    child before jax's backend initialises — see launch/proc_plane.py."""
-    if all(d.platform == "cpu" for d in sl.devices):
-        return {"JAX_PLATFORMS": "cpu",
-                "XLA_FLAGS":
-                    f"--xla_force_host_platform_device_count={sl.n_devices}"}
-    ids = ",".join(str(i) for i in sl.device_ids())
-    return {"CUDA_VISIBLE_DEVICES": ids, "JAX_VISIBLE_DEVICES": ids}
+    """The child-process environment for a spawned group process on the
+    CPU backend: a virtual-device world of the slice's size (slice identity
+    is positional there — fine, since CPU devices are fungible). Must be
+    applied in the child before jax's backend initialises — see
+    launch/proc_plane.py. An accelerator belongs to one process, so the
+    process plane is CPU-only (``Router`` refuses it elsewhere);
+    accelerator slices are driven in-process."""
+    return {"JAX_PLATFORMS": "cpu",
+            "XLA_FLAGS":
+                f"--xla_force_host_platform_device_count={sl.n_devices}"}
 
 
 def host_shm_bytes(path: str = "/dev/shm") -> Optional[int]:
@@ -210,10 +212,25 @@ class DevicePlane:
             return dict(self._owner)
 
 
-# TPU v5e hardware constants used by the roofline analysis.
-HW = {
-    "peak_flops_bf16": 197e12,     # per chip
-    "hbm_bw": 819e9,               # bytes/s per chip
-    "ici_bw": 50e9,                # bytes/s per link (~per chip per direction)
-    "hbm_bytes": 16e9,             # HBM capacity per chip
+# Per-chip peaks keyed by ``jax.Device.device_kind``. Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s inter-chip interconnect per chip, taken here as 50 GB/s per
+# link and direction).
+HW_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,
+        "hbm_bw": 819e9,
+        "ici_bw": 50e9,
+        "hbm_bytes": 16e9,
+    },
 }
+
+
+def hw_peaks(device_kind: str) -> Dict[str, float]:
+    """The peaks of one chip of ``device_kind``; a kind not in the table is
+    an error, never a default."""
+    try:
+        return HW_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(HW_PEAKS)}") from None

@@ -8,6 +8,9 @@ real inference service, through the same dataflow client API the RL
 controllers use.
 
     PYTHONPATH=src python -m repro.launch.serve --batch 8 --max-new 32
+
+As in ``launch.train``, ``--layers 0 --d-model 0 --vocab 0`` keeps the
+architecture's published widths; the defaults are a small CPU-sized cut.
 """
 from __future__ import annotations
 
@@ -20,6 +23,8 @@ import numpy as np
 
 from repro.core import api
 from repro.core.router import Router
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.train import size_overrides
 from repro.rl import data as data_lib
 
 
@@ -31,20 +36,15 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--d-model", type=int, default=256)
+    ap.add_argument("--vocab", type=int, default=512)
     ap.add_argument("--rounds", type=int, default=3)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     router = Router()
     spec = api.DeploymentSpec(
         deployment_id="serve", job_id="serve", model_name=args.arch,
-        role="rollout",
-        overrides=(
-            ("num_layers", args.layers), ("d_model", args.d_model),
-            ("num_heads", max(4, args.d_model // 64)),
-            ("num_kv_heads", max(2, args.d_model // 128)),
-            ("head_dim", 64), ("d_ff", args.d_model * 4),
-            ("vocab_size", 512),
-        ))
+        role="rollout", overrides=size_overrides(args))
     dep = router.deploy(spec, group_id=0)
 
     ds = data_lib.MathDataset(seed=0)

@@ -98,9 +98,11 @@ class BusyWPG:
         sleep = float(kw.get("sleep_s", 0.0))
         if sleep > 0.0:
             time.sleep(sleep)
-        dt = time.monotonic() - t0
+        t1 = time.monotonic()
+        # t0/t1 on the host-wide monotonic clock: intervals from different
+        # worker processes are comparable
         out = {"op": qop.op.value, "req_id": qop.req_id, "pid": os.getpid(),
-               "seconds": dt}
+               "seconds": t1 - t0, "t0": t0, "t1": t1}
         kib = int(kw.get("payload_kib", 0)) + (int(kw.get("payload_mb", 0))
                                                << 10)
         if kib > 0:

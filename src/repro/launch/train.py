@@ -8,8 +8,9 @@ multiplexing on the shared pool.
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
         --steps 50 --d-model 256 --layers 8 --ckpt-dir /tmp/plexrl_run
 
-On this CPU container the default config is a ~100M-param model; on a pod
-the same driver runs the full config (drop the size overrides).
+The defaults cut the model to 4 layers, d_model 256 and a 512-token vocab
+for a quick CPU run; ``--layers 0 --d-model 0 --vocab 0`` keeps the
+architecture's published widths (what a chip runs).
 """
 from __future__ import annotations
 
@@ -21,9 +22,12 @@ import numpy as np
 
 from repro.core.cluster import PlexCluster
 from repro.core.controller import JobConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def size_overrides(args) -> tuple:
+    """Config overrides for the ``--layers/--d-model/--vocab`` cut; a 0
+    keeps that published width."""
     ov = []
     if args.layers:
         ov.append(("num_layers", args.layers))
@@ -55,6 +59,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cluster = PlexCluster(n_groups=1)
     ov = size_overrides(args)
     for j in range(args.jobs):

@@ -355,8 +355,14 @@ def mlp_apply(p, cfg: ModelConfig, x, ctx: Optional[Ctx] = None):
 # -------------------------------------------------------------- embeddings
 
 def embed_param_specs(cfg: ModelConfig):
+    # A tied table is also the unembedding, so it takes the fan-in scale an
+    # untied unembedding gets. At unit scale the logits' std is sqrt(d_model)
+    # and each position's own token outscores the rest by ~d_model: the
+    # softmax is exactly one-hot at wide d_model and every policy gradient
+    # is exactly zero.
+    scale = cfg.d_model ** -0.5 if cfg.tie_embeddings else 1.0
     p = {"embedding": spec((cfg.vocab_size, cfg.d_model), ("vocab", "embed"),
-                           "embed", scale=1.0)}
+                           "embed", scale=scale)}
     if not cfg.tie_embeddings:
         p["unembed"] = spec((cfg.d_model, cfg.vocab_size), ("embed", "vocab"))
     return p

@@ -1,0 +1,112 @@
+"""Compiles for a described TPU v5e chip (no chip attached): the three
+Pallas kernels at the widths of the configurations they serve, and the
+full-width qwen2-0.5b UPDATE_ACTOR step with its device-memory bound.
+
+What this guards that interpret mode cannot: block shapes the TPU compiler
+tiles, Mosaic lowering of the kernel bodies, and a train step that fits one
+chip's HBM. The topology is described inside a module fixture, never at
+import, and the persistent compile cache is off around these compiles (an
+entry written for a described chip cannot be read back without one).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.worker import jit_update_actor
+from repro.kernels import decode_attention as dec
+from repro.kernels import flash_attention as fa
+from repro.kernels import ssd as ssd_k
+from repro.launch.mesh import PRODUCTION_KIND, hw_peaks
+from repro.models.registry import build_model
+from repro.rl import grpo
+from repro.train import optimizer as opt
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_flash_attention_compiles_at_qwen2_width(sds):
+    cfg = get_config("qwen2-0.5b")
+    qkv = sds((2, cfg.num_heads, 1024, cfg.resolved_head_dim))
+    compiled = _compile(
+        lambda q, k, v: fa.flash_attention_fwd(q, k, v, causal=True),
+        qkv, qkv, qkv)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_attention_compiles_at_qwen2_width(sds):
+    cfg = get_config("qwen2-0.5b")
+    d = cfg.resolved_head_dim
+    cache = sds((8, cfg.num_kv_heads, 2048, d))
+    compiled = _compile(
+        lambda q, k, v, pos: dec.decode_attention(q, k, v, pos),
+        sds((8, cfg.num_heads, d)), cache, cache, sds((), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_compiles_at_mamba2_width(sds):
+    cfg = get_config("mamba2-2.7b")
+    h, p, n, s = cfg.ssm_nheads, cfg.ssm_head_dim, cfg.ssm_state, 1024
+    f32 = jnp.float32
+    compiled = _compile(
+        lambda x, dt, a, b, c: ssd_k.ssd_chunk_scan(x, dt, a, b, c,
+                                                    chunk=cfg.ssm_chunk),
+        sds((1, h, s, p)), sds((1, h, 1, s), f32), sds((h,), f32),
+        sds((1, s, n), f32), sds((1, s, n), f32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen2_update_actor_fits_one_chip(sds):
+    """The served UPDATE_ACTOR step at published widths (B=16, S=64):
+    arguments + outputs + temporaries stay under one chip's HBM."""
+    model = build_model(get_config("qwen2-0.5b"))
+    adamw = opt.AdamWConfig()
+    on_chip = lambda t: jax.tree.map(lambda a: sds(a.shape, a.dtype), t)
+    params = on_chip(model.abstract_params())
+    opt_state = on_chip(opt.abstract_state(model.abstract_params(), adamw))
+    b, s = 16, 64
+    batch = {"tokens": sds((b, s), jnp.int32),
+             "behavior_logprobs": sds((b, s), jnp.float32),
+             "advantages": sds((b,), jnp.float32),
+             "loss_mask": sds((b, s), jnp.float32)}
+    step = jit_update_actor(model, grpo.GRPOConfig(), adamw)
+    mem = step.lower(params, opt_state, batch).compile().memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < hw_peaks(PRODUCTION_KIND)["hbm_bytes"], total
+    # the donated moments alias their outputs
+    assert mem.alias_size_in_bytes > 0

@@ -2,6 +2,9 @@
 cross-mesh migration (bit-identity, rollback), the bounded exec log, and —
 under XLA_FLAGS=--xla_force_host_platform_device_count=8 — the e2e
 disjoint-slice acceptance path."""
+import os
+import types
+
 import jax
 import numpy as np
 import pytest
@@ -10,9 +13,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import api
 from repro.core.cluster import BillingRecord, PlexCluster
 from repro.core.controller import JobConfig
-from repro.core.state_manager import StateManager, Tier
+from repro.core.router import Router
+from repro.core.state_manager import StateManager, Tier, _spec_divides
 from repro.core.worker import ExecLog
-from repro.launch.mesh import DevicePlane, make_local_mesh
+from repro.launch import compile_cache
+from repro.launch.mesh import DevicePlane, hw_peaks, make_local_mesh
 
 N_DEV = len(jax.devices())
 multi_device = pytest.mark.skipif(
@@ -73,6 +78,55 @@ def test_make_local_mesh_validates_device_count():
     msg = str(ei.value)
     assert "xla_force_host_platform_device_count" in msg
     assert str(N_DEV + 1) in msg
+
+
+def test_process_plane_refuses_accelerator_devices():
+    """A chip belongs to one process: a Router whose parent sees
+    accelerator devices must not spawn per-group children."""
+    tpu = types.SimpleNamespace(platform="tpu", id=0)
+    with pytest.raises(ValueError, match="in-process plane"):
+        Router(process_plane=True, device_plane=DevicePlane(devices=[tpu]))
+
+
+def test_hw_peaks_keyed_by_device_kind():
+    assert hw_peaks("TPU v5 lite")["peak_flops_bf16"] == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        hw_peaks("cpu")
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache sits
+    at a fixed, git-ignored .jax_cache/ in the checkout root. A process over
+    several chips turns the cache off."""
+    before = jax.config.jax_compilation_cache_dir
+    enabled = jax.config.jax_enable_compilation_cache
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.use_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        with open(os.path.join(root, ".gitignore")) as fh:
+            assert ".jax_cache/" in fh.read().split()
+        # a process over several chips reads and writes no cache at all
+        assert compile_cache.use_compile_cache(n_chips=4) is None
+        assert not jax.config.jax_enable_compilation_cache
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def test_spec_divides_checks_every_sharded_dim():
+    mesh = types.SimpleNamespace(shape={"data": 2, "model": 4})
+    assert _spec_divides((8, 6), P("model"), mesh)
+    assert not _spec_divides((6, 8), P("model"), mesh)
+    assert not _spec_divides((8, 6), P(None, "model"), mesh)
+    assert _spec_divides((8, 6), P(("data", "model")), mesh)
+    assert not _spec_divides((4, 6), P(("data", "model")), mesh)
+    assert _spec_divides((3, 5), P(), mesh)
 
 
 # ---------------------------------------------------------------- ExecLog
