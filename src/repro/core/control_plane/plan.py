@@ -97,9 +97,8 @@ class DirectorConfig:
 
 def trace_from_cycles(cycles: Sequence[Dict[str, float]],
                       nodes: int = 1) -> Optional[JobTrace]:
-    """Fold per-cycle phase durations into a JobTrace (mean per phase, the
-    same anatomy as ``traces.Profiler.trace``: training segments
-    back-to-back after the rollout gap)."""
+    """Fold per-cycle phase durations into a JobTrace (mean per phase:
+    training segments back-to-back after the rollout gap)."""
     mean: Dict[str, float] = {}
     for phase in ("rollout",) + TRAIN_PHASES:
         vals = [c[phase] for c in cycles if phase in c]

@@ -28,7 +28,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from repro.core import api
+from repro.core import api, telemetry
 from repro.core.router import Router
 from repro.rl import data as data_lib
 from repro.rl import reward as reward_lib
@@ -86,18 +86,28 @@ class _RLControllerBase:
 
     def _pack(self, prompts, answers, gen_result,
               include_rewards: bool = False) -> Dict[str, "np.ndarray"]:
+        """Rollouts to a train batch, rewards graded on the way; counted
+        as ``client.pack_s`` and, of that, ``client.reward_s``."""
         import jax.numpy as jnp
-        toks = np.asarray(gen_result["tokens"])
-        logps = np.asarray(gen_result["logprobs"])
-        texts = [data_lib.decode(t) for t in toks]
-        rewards = reward_lib.batch_rewards(texts, answers)
-        self.reward_log.append(float(rewards.mean()))
-        batch = data_lib.pack_rollout_batch(
-            prompts, toks, logps, rewards,
-            self.cfg.group_size, self.cfg.seq_len)
-        out = {k: jnp.asarray(v) for k, v in batch.items()}
-        if include_rewards:           # critic value targets need raw rewards
-            out["rewards"] = jnp.asarray(rewards)
+        tel, job = self.router.telemetry, self.cfg.job_id
+        t0 = tel.now()
+        with telemetry.span("pack", job=job):
+            with telemetry.span("pack.fetch", job=job):
+                toks = np.asarray(gen_result["tokens"])
+                logps = np.asarray(gen_result["logprobs"])
+            t_reward = tel.now()
+            with telemetry.span("reward", job=job):
+                texts = [data_lib.decode(t) for t in toks]
+                rewards = reward_lib.batch_rewards(texts, answers)
+            tel.add("client.reward_s", tel.now() - t_reward)
+            self.reward_log.append(float(rewards.mean()))
+            batch = data_lib.pack_rollout_batch(
+                prompts, toks, logps, rewards,
+                self.cfg.group_size, self.cfg.seq_len)
+            out = {k: jnp.asarray(v) for k, v in batch.items()}
+            if include_rewards:       # critic value targets need raw rewards
+                out["rewards"] = jnp.asarray(rewards)
+        tel.add("client.pack_s", tel.now() - t0)
         return out
 
     def _gate(self) -> tuple:

@@ -46,7 +46,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-from repro.core import api
+from repro.core import api, telemetry
 from repro.core.scheduler import hrrs
 from repro.core.scheduler.executor import State, Task, TaskExecutor
 from repro.core.state_manager import StateManager, Tier
@@ -116,7 +116,11 @@ class Router:
         # realized migration costs (reshard included), consumed by the
         # PlacementDirector to calibrate the migration-cost floor
         self.migrate_log: List[dict] = []
-        self.executor = TaskExecutor(now=now, policy=policy)
+        # the served path's counters (core/telemetry.py): the executor,
+        # the group StateManagers and the client controllers add to it
+        self.telemetry = telemetry.Counters(now)
+        self.executor = TaskExecutor(now=now, policy=policy,
+                                     telemetry=self.telemetry)
         # multi-tenant service layer: per-job tenant binding and HRRS
         # priority weight (rho). Unregistered jobs default to the implicit
         # default tenant at priority 1.0 — the multiplicative identity, so
@@ -159,7 +163,8 @@ class Router:
                 sm = self._spawn_group_process(group_id, sl)
             else:
                 sm = StateManager(node_id=f"group{group_id}",
-                                  clock=self.now, mesh_slice=sl)
+                                  clock=self.now, mesh_slice=sl,
+                                  telemetry=self.telemetry)
             self.state_managers[group_id] = sm
         elif sm.mesh_slice is None:
             sm.mesh_slice = self.device_plane.slice_for_group(group_id)
@@ -332,10 +337,14 @@ class Router:
                         and (w := self.wpgs.get(d)) is not None
                         and w.spec.job_id != qop.job_id]
         resident = [w for w in resident if w.resident()]
-        t_off = 0.0
-        for w in resident:
-            t_off += w.offload(Tier.HOST)
-        t_load = target_wpg.ensure_resident()
+        if resident:
+            # a switch as switches are counted: it moved a job off the group
+            self.telemetry.add("switch.count")
+            with telemetry.span("switch", job=qop.job_id, req=qop.req_id):
+                t_off = sum(w.offload(Tier.HOST) for w in resident)
+                t_load = target_wpg.ensure_resident()
+        else:
+            t_off, t_load = 0.0, target_wpg.ensure_resident()
         if resident or t_load > 0:
             with self.executor.cv:
                 self.switch_log.append({
@@ -350,15 +359,21 @@ class Router:
 
     def _resolve_future(self, qop: api.QueuedOperation, result,
                         err: Optional[BaseException]):
+        """Resolve the op's future, firing its client callbacks on this
+        thread. On a dispatch thread its group admits nothing meanwhile:
+        that time is counted as ``dispatch.callback_s``."""
+        t0 = self.now()
         try:
-            if err is None:
-                qop.future.set_result(result)
-            else:
-                qop.future.set_error(err)
+            with telemetry.span("callbacks", job=qop.job_id, req=qop.req_id):
+                if err is None:
+                    qop.future.set_result(result)
+                else:
+                    qop.future.set_error(err)
         except Exception as cb_err:  # noqa: BLE001 - user callback bug
             logger.warning("callback for op %d raised: %r",
                            qop.req_id, cb_err)
             self.callback_errors.append((qop.req_id, cb_err))
+        self.telemetry.add("dispatch.callback_s", self.now() - t0)
 
     def _raise_callback_errors(self, since: int):
         """Drivers fail loudly at exit if any user callback raised during
@@ -459,7 +474,7 @@ class Router:
                     f"deployment {qop.deployment_id} torn down")
             if qop.op not in (api.Op.INIT,):
                 self._handle_job_transition(group_id, qop, wpg)
-            result = wpg.execute(qop)
+            result = self._execute_timed(qop, wpg)
         except Exception as e:  # noqa: BLE001 - surface via future
             err = e
         with self.executor.cv:
@@ -467,6 +482,23 @@ class Router:
                                  else str(err))
             self._finalize(qop)
         self._resolve_future(qop, result, err)
+
+    def _execute_timed(self, qop: api.QueuedOperation, wpg):
+        """``wpg.execute`` in a ``plexrl.op.<OP>`` span, counted as
+        ``op.<OP>.count``, ``op.<OP>.host_s`` (the call) and
+        ``op.<OP>.ready_s`` (the call until its results are ready)."""
+        name, tel = qop.op.name, self.telemetry
+        # the trace's encoding of span stats splits on commas
+        cause = " ".join(str(r) for r in qop.prerequisites)
+        t0 = self.now()
+        with telemetry.span(f"op.{name}", job=qop.job_id, req=qop.req_id,
+                            cause=cause):
+            result = wpg.execute(qop)
+        tel.add(f"op.{name}.count")
+        tel.add(f"op.{name}.host_s", self.now() - t0)
+        tel.when_ready(result,
+                       lambda t: tel.add(f"op.{name}.ready_s", t - t0))
+        return result
 
     # ------------------------------------------------------ serial driver
     def step(self, max_ops: int = 1) -> int:

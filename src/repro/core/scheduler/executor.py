@@ -32,6 +32,7 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence
 
 from repro.core.scheduler import hrrs
 from repro.core.scheduler.admission_index import GroupAdmissionIndex
+from repro.core.telemetry import Counters
 
 
 class State(enum.Enum):
@@ -48,6 +49,7 @@ class Task:
     state: State = State.QUEUED
     prerequisites: tuple = ()          # req_ids that must COMPLETE first
     t_admitted: float = 0.0
+    t_ready: float = 0.0               # when its last prerequisite completed
     t_started: float = 0.0
     t_finished: float = 0.0
     error: Optional[str] = None
@@ -119,8 +121,12 @@ class TaskExecutor:
     def __init__(self, now: Callable[[], float],
                  t_load: float = 0.0, t_offload: float = 0.0,
                  policy: str = "hrrs", use_admission_index: bool = True,
-                 max_settled_tasks: int = 4096, phase_window: int = 256):
+                 max_settled_tasks: int = 4096, phase_window: int = 256,
+                 telemetry: Optional[Counters] = None):
         self.now = now
+        # dispatch.queue_wait_s (admissible to started, total and per op
+        # kind) and dispatch.admitted; the Router passes its own
+        self.telemetry = telemetry or Counters(now)
         self.t_load = t_load
         self.t_offload = t_offload
         # admission fallbacks for groups with no measured switch yet; the
@@ -155,6 +161,7 @@ class TaskExecutor:
         self.use_admission_index = use_admission_index and policy == "hrrs"
         self._indexes: Dict[int, GroupAdmissionIndex] = {}
         # prereq req_id -> dependents whose readiness flips when it settles
+        # (their t_ready, and their index membership under hrrs)
         self._dependents: Dict[int, List[int]] = {}
         # Bounded retention of settled Task records (telemetry): settled
         # req_ids enter a FIFO ring; beyond ``max_settled_tasks`` the oldest
@@ -226,13 +233,13 @@ class TaskExecutor:
                    and self.tasks[p].state == State.FAILED
                    for p in t.prerequisites):
                 self.poison_dirty = True   # born poisoned: needs a sweep
+            for p in t.prerequisites:
+                pt = self.tasks.get(p)
+                if pt is None or pt.state in (State.QUEUED, State.RUNNING):
+                    self._dependents.setdefault(p, []).append(request.req_id)
+            if self._prereqs_done(t):
+                t.t_ready = t.t_admitted
             if self.use_admission_index:
-                for p in t.prerequisites:
-                    pt = self.tasks.get(p)
-                    if pt is None or pt.state in (State.QUEUED,
-                                                  State.RUNNING):
-                        self._dependents.setdefault(p, []).append(
-                            request.req_id)
                 if self._ready(t):
                     self._index_insert(t)
                 # a task counted "ready" only because this req_id was an
@@ -248,11 +255,14 @@ class TaskExecutor:
             return t
 
     # ---------------------------------------------------------- admission
+    def _prereqs_done(self, t: Task) -> bool:
+        return all(self.tasks[p].state == State.COMPLETED
+                   for p in t.prerequisites if p in self.tasks)
+
     def _ready(self, t: Task) -> bool:
         return (t.state == State.QUEUED
                 and t.request.job_id not in self.held_jobs
-                and all(self.tasks[p].state == State.COMPLETED
-                        for p in t.prerequisites if p in self.tasks))
+                and self._prereqs_done(t))
 
     def failed_prereqs(self, t: Task) -> List[int]:
         return [p for p in t.prerequisites
@@ -325,6 +335,11 @@ class TaskExecutor:
             self.resident_job[task.group_id] = task.request.job_id
             task.state = State.RUNNING
             task.t_started = self.now()
+            wait = task.t_started - task.t_ready
+            self.telemetry.add("dispatch.admitted")
+            self.telemetry.add("dispatch.queue_wait_s", wait)
+            self.telemetry.add(
+                f"dispatch.queue_wait_s.{task.request.op.upper()}", wait)
             self.queued_count[task.group_id] -= 1
             job = task.request.job_id
             self._running_count[job] = self._running_count.get(job, 0) + 1
@@ -384,25 +399,28 @@ class TaskExecutor:
             if self.use_admission_index:
                 # poisoned-while-QUEUED tasks may still be indexed
                 self._index_remove(task)
-                deps = self._dependents.pop(task.request.req_id, None)
-                if deps and not error:
-                    for d in deps:
-                        dt = self.tasks.get(d)
-                        if (dt is not None and dt.state == State.QUEUED
-                                and self._ready(dt)):
-                            self._index_insert(dt)
-                # scrub this task's own registrations under still-pending
-                # prereqs (incl. forward-referenced ids that never arrived)
-                # so _dependents stays bounded by open tasks
-                for p in task.prerequisites:
-                    waiters = self._dependents.get(p)
-                    if waiters is not None:
-                        try:
-                            waiters.remove(task.request.req_id)
-                        except ValueError:
-                            pass
-                        if not waiters:
-                            del self._dependents[p]
+            deps = self._dependents.pop(task.request.req_id, None)
+            if deps and not error:
+                for d in deps:
+                    dt = self.tasks.get(d)
+                    if (dt is None or dt.state != State.QUEUED
+                            or not self._prereqs_done(dt)):
+                        continue
+                    dt.t_ready = task.t_finished
+                    if self.use_admission_index and self._ready(dt):
+                        self._index_insert(dt)
+            # scrub this task's own registrations under still-pending
+            # prereqs (incl. forward-referenced ids that never arrived)
+            # so _dependents stays bounded by open tasks
+            for p in task.prerequisites:
+                waiters = self._dependents.get(p)
+                if waiters is not None:
+                    try:
+                        waiters.remove(task.request.req_id)
+                    except ValueError:
+                        pass
+                    if not waiters:
+                        del self._dependents[p]
             self._settled.append(task.request.req_id)
             self._prune_settled()
             self.cv.notify_all()
@@ -536,7 +554,3 @@ class TaskExecutor:
         """Tasks still QUEUED or RUNNING (idle when 0 and inflight == 0)."""
         with self.cv:
             return self._open
-
-    def wait_time(self, task: Task) -> float:
-        start = task.t_started if task.t_started else self.now()
-        return max(0.0, start - task.t_admitted)

@@ -18,23 +18,30 @@ Key mechanisms reproduced:
 - §4.5.4 off-critical-path work: a host-resident AdamW step (the CPU
   optimizer of ZeRO-offload) over canonical host state.
 
-All transfer timings are recorded; HRRS pulls its C_setup estimates from
-``load_time_estimate`` / ``offload_time_estimate``.
+Transfer timings feed a bandwidth estimate; HRRS pulls its C_setup
+estimates from ``load_time_estimate`` / ``offload_time_estimate``. Those
+are enqueue times: a load returns once its copies are issued. With the
+Router's counters attached (``telemetry``), each tier move opens a
+``plexrl.switch.offload`` / ``plexrl.switch.load`` span and adds
+``switch.offload_s`` / ``switch.offload_bytes`` and ``switch.load_bytes``,
+and ``switch.load_s`` once every loaded array is ready on the device.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
 import logging
 import os
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.core import telemetry as telemetry_lib
 from repro.models import common
 
 logger = logging.getLogger(__name__)
@@ -88,7 +95,8 @@ class StateManager:
                  host_capacity: float = float("inf"),
                  disk_dir: Optional[str] = None,
                  clock: Callable[[], float] = time.monotonic,
-                 mesh_slice=None):
+                 mesh_slice=None,
+                 telemetry: Optional[telemetry_lib.Counters] = None):
         self.node_id = node_id
         self.device_capacity = device_capacity
         self.host_capacity = host_capacity
@@ -98,7 +106,7 @@ class StateManager:
         # live on these devices; None = wherever jax defaults (legacy view)
         self.mesh_slice = mesh_slice
         self.entries: Dict[str, Entry] = {}
-        self.transfer_log: List[Tuple[str, str, int, float]] = []
+        self.telemetry = telemetry
         self._bw_estimate: Dict[str, float] = {}   # bytes/s per direction
         self.last_migrate: Optional[Dict[str, Any]] = None
 
@@ -110,7 +118,6 @@ class StateManager:
         return {t.name: self._tier_bytes(t) for t in Tier}
 
     def _record(self, direction: str, nbytes: int, dt: float):
-        self.transfer_log.append((direction, "", nbytes, dt))
         if dt > 0 and nbytes > 0:
             bw = nbytes / dt
             old = self._bw_estimate.get(direction)
@@ -119,6 +126,17 @@ class StateManager:
     def _estimate(self, direction: str, nbytes: int, default_bw: float) -> float:
         bw = self._bw_estimate.get(direction, default_bw)
         return nbytes / max(bw, 1.0)
+
+    def _move_span(self, name: str, keys: Sequence[str],
+                   moves: Callable[[Entry], bool]):
+        """A ``plexrl.<name>`` span over a tier move, opened only where
+        some entry of ``keys`` moves (attributes: job, bytes)."""
+        todo = [e for k in keys
+                if (e := self.entries.get(k)) is not None and moves(e)]
+        if not todo:
+            return contextlib.nullcontext()
+        return telemetry_lib.span(name, job=todo[0].key.split("/", 1)[0],
+                                  bytes=sum(e.nbytes for e in todo))
 
     @staticmethod
     def _leaf_spec(leaf):
@@ -192,31 +210,36 @@ class StateManager:
         deterministic."""
         t0 = self.clock()
         moved = 0
-        for k in keys:
-            e = self.entries.get(k)
-            # a key may vanish mid-iteration when a deployment detaches
-            # concurrently (teardown unregisters); skipping it is the move
-            if e is None or e.tier >= to:
-                continue
-            if to == Tier.HOST:
-                arr = np.asarray(jax.device_get(e.ref))
-                e.ref = arr
-            else:  # DISK
-                if e.tier == Tier.DEVICE:
-                    e.ref = np.asarray(jax.device_get(e.ref))
-                os.makedirs(self.disk_dir, exist_ok=True)
-                path = os.path.join(self.disk_dir,
-                                    k.replace("/", "__") + ".npy")
-                arr = e.ref
-                e.is_bf16 = arr.dtype == jnp.bfloat16
-                np.save(path, arr.view(np.uint16) if e.is_bf16 else arr)
-                e.path = path
-                e.ref = None
-            e.tier = to
-            e.last_touch = self.clock()
-            moved += e.nbytes
+        with self._move_span("switch.offload", keys, lambda e: e.tier < to):
+            for k in keys:
+                e = self.entries.get(k)
+                # a key may vanish mid-iteration when a deployment detaches
+                # concurrently (teardown unregisters); skipping it is the move
+                if e is None or e.tier >= to:
+                    continue
+                if to == Tier.HOST:
+                    arr = np.asarray(jax.device_get(e.ref))
+                    e.ref = arr
+                else:  # DISK
+                    if e.tier == Tier.DEVICE:
+                        e.ref = np.asarray(jax.device_get(e.ref))
+                    os.makedirs(self.disk_dir, exist_ok=True)
+                    path = os.path.join(self.disk_dir,
+                                        k.replace("/", "__") + ".npy")
+                    arr = e.ref
+                    e.is_bf16 = arr.dtype == jnp.bfloat16
+                    np.save(path, arr.view(np.uint16) if e.is_bf16 else arr)
+                    e.path = path
+                    e.ref = None
+                e.tier = to
+                e.last_touch = self.clock()
+                moved += e.nbytes
         dt = self.clock() - t0
         self._record("offload", moved, dt)
+        if moved and self.telemetry is not None:
+            # synchronous (device_get), so the clock already timed it
+            self.telemetry.add("switch.offload_s", dt)
+            self.telemetry.add("switch.offload_bytes", moved)
         return dt
 
     def prefetch(self, keys: Sequence[str], shardings=None) -> float:
@@ -224,33 +247,44 @@ class StateManager:
         ``self.clock`` for the same determinism contract as offload."""
         t0 = self.clock()
         moved = 0
-        for i, k in enumerate(keys):
-            e = self.entries.get(k)
-            if e is None or e.tier == Tier.DEVICE:
-                continue
-            if e.tier == Tier.DISK:
-                arr = np.load(e.path)
-                if e.is_bf16:
-                    arr = arr.view(jnp.bfloat16)
-                e.ref = arr
-            arr = e.ref
-            shd = None
-            if shardings is not None:
-                shd = shardings[i] if isinstance(shardings, (list, tuple)) \
-                    else shardings.get(k)
-            if shd is not None:
-                e.ref = jax.device_put(arr, shd)
-                e.spec = shd.spec if isinstance(shd, NamedSharding) else e.spec
-            else:
-                # no explicit target layout: restore the entry's recorded
-                # spec on THIS node's mesh slice (device-aware residency)
-                e.ref = self._to_device(arr, e.spec)
-                e.spec = self._leaf_spec(e.ref)
-            e.tier = Tier.DEVICE
-            e.last_touch = self.clock()
-            moved += e.nbytes
+        loaded = []
+        with self._move_span("switch.load", keys,
+                             lambda e: e.tier != Tier.DEVICE):
+            for i, k in enumerate(keys):
+                e = self.entries.get(k)
+                if e is None or e.tier == Tier.DEVICE:
+                    continue
+                if e.tier == Tier.DISK:
+                    arr = np.load(e.path)
+                    if e.is_bf16:
+                        arr = arr.view(jnp.bfloat16)
+                    e.ref = arr
+                arr = e.ref
+                shd = None
+                if shardings is not None:
+                    shd = (shardings[i]
+                           if isinstance(shardings, (list, tuple))
+                           else shardings.get(k))
+                if shd is not None:
+                    e.ref = jax.device_put(arr, shd)
+                    if isinstance(shd, NamedSharding):
+                        e.spec = shd.spec
+                else:
+                    # no explicit target layout: restore the entry's recorded
+                    # spec on THIS node's mesh slice (device-aware residency)
+                    e.ref = self._to_device(arr, e.spec)
+                    e.spec = self._leaf_spec(e.ref)
+                e.tier = Tier.DEVICE
+                e.last_touch = self.clock()
+                moved += e.nbytes
+                loaded.append(e.ref)
         dt = self.clock() - t0
         self._record("load", moved, dt)
+        tel = self.telemetry
+        if moved and tel is not None:
+            tel.add("switch.load_bytes", moved)
+            tel.when_ready(loaded, lambda t: tel.add("switch.load_s", t - t0),
+                           donated="switch.load_donated")
         self._evict_if_needed()
         return dt
 

@@ -13,7 +13,7 @@ long-tail jitter from the tool-stall model (§2).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -101,26 +101,3 @@ def synthetic_job_mix(n_jobs: int, seed: int = 0,
         ))
     return out
 
-
-class Profiler:
-    """Cold-start profiler: records phase durations over one isolated cycle
-    and emits the JobTrace used for warm placement."""
-
-    def __init__(self):
-        self.samples: Dict[str, List[float]] = {}
-
-    def record(self, phase: str, duration: float):
-        self.samples.setdefault(phase, []).append(duration)
-
-    def trace(self, nodes: int = 1) -> Optional[JobTrace]:
-        needed = ("rollout", "update_actor")
-        if not all(p in self.samples for p in needed):
-            return None
-        mean = {p: float(np.mean(v)) for p, v in self.samples.items()}
-        t = mean.get("rollout", 0.0)
-        segs = []
-        for p in ("compute_log_prob", "update_actor", "sync_weight"):
-            if p in mean:
-                segs.append((t, mean[p]))
-                t += mean[p]
-        return JobTrace(period=t, segments=tuple(segs), nodes=nodes)

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.core import api
+from repro.core import api, telemetry
 from repro.core.state_manager import StateManager, Tier
 from repro.models import sharding as shd
 from repro.models.registry import Model, build_model
@@ -330,10 +330,13 @@ class WorkerProcessGroup:
         if self._update_actor is None:
             self._update_actor = jit_update_actor(self.model, self.grpo_cfg,
                                                   self.adamw_cfg)
-        new_state, metrics = self._update_actor(self.params(),
-                                                self.opt_state(), batch)
-        self._store(params=new_state.params, opt_state=new_state.opt_state)
-        return {k: float(v) for k, v in metrics.items()}
+        with telemetry.span("update.launch", job=self.spec.job_id):
+            new_state, metrics = self._update_actor(self.params(),
+                                                    self.opt_state(), batch)
+            self._store(params=new_state.params,
+                        opt_state=new_state.opt_state)
+        with telemetry.span("update.sync", job=self.spec.job_id):
+            return {k: float(v) for k, v in metrics.items()}
 
     def _op_sync_weights(self, target_wpg: "WorkerProcessGroup",
                          target_shardings=None):

@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import telemetry
 from repro.models.layers import Ctx
 from repro.models.registry import Model
 
@@ -48,9 +49,11 @@ def rollout(model: Model, params, prompt_tokens, rng,
     Returns (completions (B, N), logprobs (B, N), done_mask (B, N)).
     """
     batch = {"tokens": prompt_tokens, **(extra_inputs or {})}
-    last_logits, _, cache = model.forward(params, batch, ctx, return_cache=True)
-    last_logits = last_logits[:, -1]
-    cache = _pad_cache(cache, cfg.max_new_tokens)
+    with telemetry.span("generate.prefill"):
+        last_logits, _, cache = model.forward(params, batch, ctx,
+                                              return_cache=True)
+        last_logits = last_logits[:, -1]
+        cache = _pad_cache(cache, cfg.max_new_tokens)
 
     def sample(logits, key):
         logits = logits.astype(jnp.float32)
@@ -70,8 +73,9 @@ def rollout(model: Model, params, prompt_tokens, rng,
         alive = alive & (tok != cfg.eos_id)
         return (new_logits[:, -1], new_cache, alive), (tok, logp, alive)
 
-    keys = jax.random.split(rng, cfg.max_new_tokens)
-    b = prompt_tokens.shape[0]
-    init = (last_logits, cache, jnp.ones((b,), bool))
-    _, (toks, logps, alive) = jax.lax.scan(step, init, keys)
-    return toks.T, logps.T, alive.T
+    with telemetry.span("generate.decode"):
+        keys = jax.random.split(rng, cfg.max_new_tokens)
+        b = prompt_tokens.shape[0]
+        init = (last_logits, cache, jnp.ones((b,), bool))
+        _, (toks, logps, alive) = jax.lax.scan(step, init, keys)
+        return toks.T, logps.T, alive.T
