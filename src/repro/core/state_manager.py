@@ -3,7 +3,9 @@
 Three-tier hierarchy adapted to this runtime:
 
     DEVICE  — accelerator memory (jax arrays, possibly sharded)
-    HOST    — canonicalised numpy buffers ("pinned host memory")
+    HOST    — canonicalised host buffers: pinned-host jax arrays where an
+              offload put them, numpy where the host produced them
+              (``np.asarray(entry.ref)`` gives numpy either way)
     DISK    — .npz spill files ("NVMe", via repro.train.checkpoint shards)
 
 Key mechanisms reproduced:
@@ -18,19 +20,29 @@ Key mechanisms reproduced:
 - §4.5.4 off-critical-path work: a host-resident AdamW step (the CPU
   optimizer of ZeRO-offload) over canonical host state.
 
-Transfer timings feed a bandwidth estimate; HRRS pulls its C_setup
-estimates from ``load_time_estimate`` / ``offload_time_estimate``. Those
-are enqueue times: a load returns once its copies are issued. With the
-Router's counters attached (``telemetry``), each tier move opens a
-``plexrl.switch.offload`` / ``plexrl.switch.load`` span and adds
-``switch.offload_s`` / ``switch.offload_bytes`` and ``switch.load_bytes``,
-and ``switch.load_s`` once every loaded array is ready on the device.
+Both directions move a call's leaves as one batch: an offload issues every
+device-to-host copy before it waits on any (one jitted program of
+asynchronous copies into pinned host memory) and returns once all have
+landed; a load issues every host-to-device copy and returns without
+waiting. A load keeps each leaf's pinned buffer (``StateManager._slots``,
+by key, across re-registration), and the key's next offload lands in it
+(donated): after a job's first offload, a switch neither allocates nor
+pins host memory. Transfer timings feed a bandwidth estimate; HRRS pulls
+its C_setup estimates from ``load_time_estimate`` /
+``offload_time_estimate``, so the load's is an enqueue time and the
+offload's the transfer's. With the Router's counters attached
+(``telemetry``), each tier move opens a ``plexrl.switch.offload`` /
+``plexrl.switch.load`` span and adds ``switch.offload_s`` /
+``switch.offload_bytes`` / ``switch.offload_leaves`` and
+``switch.load_bytes``, and ``switch.load_s`` once every loaded array is
+ready on the device.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import enum
+import functools
 import logging
 import os
 import time
@@ -58,7 +70,8 @@ class Entry:
     key: str                         # canonical logical key (job-scoped)
     tier: Tier
     nbytes: int
-    ref: Any = None                  # jax array (DEVICE) / np array (HOST)
+    ref: Any = None                  # jax array (DEVICE) / pinned-host
+    #   jax array or np array (HOST)
     path: Optional[str] = None       # DISK shard path
     version: int = 0
     refcount: int = 1                # dedup count across logical replicas
@@ -71,6 +84,29 @@ class Entry:
 
 def _nbytes(x) -> int:
     return int(np.prod(x.shape)) * x.dtype.itemsize
+
+
+@functools.partial(jax.jit, donate_argnums=0, keep_unused=True)
+def _copy_into(slots, srcs):
+    """Copy every leaf of ``srcs`` to pinned host memory in one program: all
+    the copies start before any is waited on. A leaf of ``slots`` (or None)
+    is a pinned buffer of the same shape and layout, donated to hold it."""
+    return [jax.device_put(x, jax.memory.Space.Host) for x in srcs]
+
+
+def _to_host(srcs: List[jax.Array], slots: List[Any]) -> List[jax.Array]:
+    """``srcs`` copied to pinned host memory, once every copy has landed.
+    The CPU backend cannot place a program's outputs in host memory (its
+    device memory is host memory): there the copies are one batched
+    ``device_put`` that reuses no buffer."""
+    if not srcs:
+        return []
+    if next(iter(srcs[0].devices())).platform == "cpu":
+        out = jax.device_put(
+            srcs, [x.sharding.with_memory_kind("pinned_host") for x in srcs])
+    else:
+        out = _copy_into(slots, srcs)
+    return jax.block_until_ready(out)
 
 
 def _spec_divides(shape, spec, mesh) -> bool:
@@ -107,6 +143,10 @@ class StateManager:
         self.mesh_slice = mesh_slice
         self.entries: Dict[str, Entry] = {}
         self.telemetry = telemetry
+        # pinned-host buffers loads came from, by key, for the key's next
+        # offload to land in; not counted in HOST, dropped with the job's
+        # last entry (WPGs re-register a job's keys after every update)
+        self._slots: Dict[str, jax.Array] = {}
         self._bw_estimate: Dict[str, float] = {}   # bytes/s per direction
         self.last_migrate: Optional[Dict[str, Any]] = None
 
@@ -162,6 +202,8 @@ class StateManager:
             # replicate across the slice: compatible under jit with leaves
             # that DID reshard onto the slice's mesh
             return jax.device_put(arr, NamedSharding(mesh, PartitionSpec()))
+        if isinstance(arr, jax.Array):   # a pinned-host buffer
+            return jax.device_put(arr, arr.sharding.with_memory_kind("device"))
         return jnp.asarray(arr)
 
     # ----------------------------------------------------------- register
@@ -190,6 +232,7 @@ class StateManager:
         return [k for k in self.entries if k.startswith(pre)]
 
     def unregister(self, keys: Sequence[str]):
+        jobs = set()
         for k in keys:
             e = self.entries.get(k)
             if e is None:
@@ -199,34 +242,58 @@ class StateManager:
                 if e.path and os.path.exists(e.path):
                     os.unlink(e.path)
                 del self.entries[k]
+                jobs.add(k.split("/", 1)[0] + "/")
+        for pre in jobs:
+            if not any(k.startswith(pre) for k in list(self.entries)):
+                for k in list(self._slots):
+                    if k.startswith(pre):
+                        self._slots.pop(k, None)
+
+    def _take_slot(self, e: Entry):
+        """The key's pinned buffer, if ``e``'s offload can land in it: same
+        shape and dtype, and the same per-device layout (a step's outputs
+        may name it by another spec than the load did)."""
+        s, x = self._slots.pop(e.key, None), e.ref
+        if (s is None or s.shape != x.shape or s.dtype != x.dtype
+                or not s.sharding.is_equivalent_to(
+                    x.sharding.with_memory_kind("pinned_host"), x.ndim)):
+            return None
+        return s
 
     # ------------------------------------------------------ tier movement
     def offload(self, keys: Sequence[str], to: Tier = Tier.HOST) -> float:
         """Move state down the hierarchy. Returns elapsed seconds.
+
+        Every device-to-host copy of the call is issued before any is
+        waited on (:func:`_to_host`), each into the pinned buffer its key
+        was last loaded from where there is one, and the call returns once
+        all have landed; only then are the device buffers dropped.
 
         Timed through the injected ``self.clock`` (NOT time.monotonic): under
         a VirtualClock replay transfers take zero virtual time, so measured
         C_setup feedback — and therefore HRRS admission — stays
         deterministic."""
         t0 = self.clock()
-        moved = 0
+        moved = leaves = 0
         with self._move_span("switch.offload", keys, lambda e: e.tier < to):
-            for k in keys:
-                e = self.entries.get(k)
-                # a key may vanish mid-iteration when a deployment detaches
+            todo = [e for k in keys
+                    if (e := self.entries.get(k)) is not None and e.tier < to]
+            on_device = [e for e in todo if e.tier == Tier.DEVICE
+                         and isinstance(e.ref, jax.Array)]
+            landed = _to_host([e.ref for e in on_device],
+                              [self._take_slot(e) for e in on_device])
+            for e, arr in zip(on_device, landed):
+                e.ref = arr
+            for e in todo:
+                # a key may vanish mid-offload when a deployment detaches
                 # concurrently (teardown unregisters); skipping it is the move
-                if e is None or e.tier >= to:
+                if self.entries.get(e.key) is not e:
                     continue
-                if to == Tier.HOST:
-                    arr = np.asarray(jax.device_get(e.ref))
-                    e.ref = arr
-                else:  # DISK
-                    if e.tier == Tier.DEVICE:
-                        e.ref = np.asarray(jax.device_get(e.ref))
+                if to == Tier.DISK:
                     os.makedirs(self.disk_dir, exist_ok=True)
                     path = os.path.join(self.disk_dir,
-                                        k.replace("/", "__") + ".npy")
-                    arr = e.ref
+                                        e.key.replace("/", "__") + ".npy")
+                    arr = np.asarray(e.ref)
                     e.is_bf16 = arr.dtype == jnp.bfloat16
                     np.save(path, arr.view(np.uint16) if e.is_bf16 else arr)
                     e.path = path
@@ -234,12 +301,14 @@ class StateManager:
                 e.tier = to
                 e.last_touch = self.clock()
                 moved += e.nbytes
+                leaves += 1
         dt = self.clock() - t0
         self._record("offload", moved, dt)
         if moved and self.telemetry is not None:
-            # synchronous (device_get), so the clock already timed it
+            # synchronous (every copy has landed), so the clock timed it
             self.telemetry.add("switch.offload_s", dt)
             self.telemetry.add("switch.offload_bytes", moved)
+            self.telemetry.add("switch.offload_leaves", leaves)
         return dt
 
     def prefetch(self, keys: Sequence[str], shardings=None) -> float:
@@ -274,6 +343,8 @@ class StateManager:
                     # spec on THIS node's mesh slice (device-aware residency)
                     e.ref = self._to_device(arr, e.spec)
                     e.spec = self._leaf_spec(e.ref)
+                if isinstance(arr, jax.Array):
+                    self._slots[k] = arr      # the next offload lands here
                 e.tier = Tier.DEVICE
                 e.last_touch = self.clock()
                 moved += e.nbytes
@@ -313,7 +384,7 @@ class StateManager:
     # ------------------------------------------------------- gather trees
     def gather(self, job_id: str, template, prefix: str = "params"):
         """Rebuild a pytree from managed entries (any tier; loads lazily from
-        disk, leaves host tensors as numpy)."""
+        disk, gives host tensors as numpy)."""
         flat = {}
         pre = f"{job_id}/{prefix}/"
         for k, e in self.entries.items():
@@ -323,6 +394,8 @@ class StateManager:
                 arr = np.load(e.path)
                 if e.is_bf16:
                     arr = arr.view(jnp.bfloat16)
+            elif e.tier == Tier.HOST:
+                arr = np.asarray(e.ref)
             else:
                 arr = e.ref
             flat[k[len(pre):]] = arr
@@ -387,7 +460,7 @@ class StateManager:
                     if e.is_bf16:
                         arr = arr.view(jnp.bfloat16)
                 else:
-                    arr = e.ref
+                    arr = np.asarray(e.ref)
                 tier, ref, spec = Tier.HOST, arr, e.spec
                 if e.tier == Tier.DEVICE and dst.mesh_slice is not None:
                     # reshard onto the target slice: the entry arrives

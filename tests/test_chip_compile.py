@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e chip (no chip attached): the three
 Pallas kernels at the widths of the configurations they serve, and the
-full-width qwen2-0.5b UPDATE_ACTOR step with its device-memory bound.
+full-width qwen2-0.5b UPDATE_ACTOR step with its device-memory bound, and
+the state plane's offload copy into reused pinned host buffers.
 
 What this guards that interpret mode cannot: block shapes the TPU compiler
 tiles, Mosaic lowering of the kernel bodies, and a train step that fits one
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core import state_manager as sm_lib
 from repro.core.worker import jit_update_actor
 from repro.kernels import decode_attention as dec
 from repro.kernels import flash_attention as fa
@@ -110,3 +112,22 @@ def test_qwen2_update_actor_fits_one_chip(sds):
     assert total < hw_peaks(PRODUCTION_KIND)["hbm_bytes"], total
     # the donated moments alias their outputs
     assert mem.alias_size_in_bytes > 0
+
+
+def test_offload_copies_land_in_the_donated_pinned_buffers(topo, sds):
+    """The offload's one program (``state_manager._copy_into``) over a
+    job's stacked leaves: every output is in pinned host memory, and the
+    outputs of the leaves given a buffer alias it (no allocation)."""
+    pinned = SingleDeviceSharding(topo.devices[0], memory_kind="pinned_host")
+    leaves = [sds((24, 896, 4864), jnp.float32), sds((24, 896)),
+              sds((151936, 896))]
+    slots = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=pinned)
+             for x in leaves]
+    slots[1] = None                     # a leaf with no buffer yet
+    compiled = sm_lib._copy_into.lower(slots, leaves).compile()
+    assert all(s.memory_kind == "pinned_host"
+               for s in compiled.output_shardings)
+    mem = compiled.memory_analysis()
+    given = sum(x.size * x.dtype.itemsize for x in slots if x is not None)
+    assert mem.host_alias_size_in_bytes == given
+    assert mem.host_output_size_in_bytes == given + 24 * 896 * 2

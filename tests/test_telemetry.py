@@ -172,6 +172,7 @@ def test_switch_counters_move_the_jobs_bytes(tmp_path):
     sm = router.state_managers[0]
     nbytes = [sm.job_bytes(dep.wpg.job_prefix) for dep in deps]
     assert all(nbytes)
+    leaves = [len(sm.keys_for(dep.wpg.job_prefix)) for dep in deps]
     deps[1].save_checkpoint(str(tmp_path / "b"))
     router.drain()
     deps[0].save_checkpoint(str(tmp_path / "a"))
@@ -179,6 +180,8 @@ def test_switch_counters_move_the_jobs_bytes(tmp_path):
     snap = router.telemetry.snapshot()
     assert snap["switch.count"] == 2
     assert snap["switch.offload_bytes"] == nbytes[0] + nbytes[1]
+    # each offload moved all of its job's leaves in one batch
+    assert snap["switch.offload_leaves"] == leaves[0] + leaves[1]
     assert snap["switch.load_bytes"] == nbytes[0]
     assert snap["op.SAVE_CHECKPOINT.count"] == 2
     assert wait_for(router.telemetry, "switch.load_s") >= 0.0
