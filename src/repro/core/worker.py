@@ -255,7 +255,7 @@ class WorkerProcessGroup:
             self.model, params, jnp.asarray(prompt_tokens), k,
             rollout_lib.RolloutConfig(max_new_tokens=max_new_tokens,
                                       temperature=temperature),
-            extra_inputs=extra_inputs)
+            extra_inputs=extra_inputs, counters=self.sm.telemetry)
         return {"tokens": toks, "logprobs": logps, "alive": alive}
 
     def _op_forward(self, batch, output: str = "logprobs"):

@@ -9,6 +9,10 @@ families run the ``long_500k`` cell.
 The chunk kernel has a Pallas TPU implementation in
 ``repro.kernels.ssd`` (this module is also its jnp oracle via
 ``cfg.attn_impl == "xla"``).
+
+The causal conv, the chunked scan and the one-token update run under
+``jax.named_scope`` ``ssd.conv``, ``ssd.chunk`` and ``ssd.decode``, so the
+device ops they compile to carry those names in a profile's op metadata.
 """
 from __future__ import annotations
 
@@ -216,12 +220,14 @@ def mixer_apply(p, cfg: ModelConfig, x, ctx: Optional[Ctx] = None,
 
     if cache is None:
         xBC_raw = xBC
-        xBC = causal_conv(xBC, p["conv_w"], p["conv_b"])
+        with jax.named_scope("ssd.conv"):
+            xBC = causal_conv(xBC, p["conv_w"], p["conv_b"])
         xs, B, C = _split_xbc(cfg, xBC)
         xs = constrain(ctx, xs, ("batch", "seq", "ssm_inner"))
-        y, final_state = ssd_chunked(xs.reshape(b, s, h, pdim), dt, A,
-                                     B.reshape(b, s, g, n), C.reshape(b, s, g, n),
-                                     cfg.ssm_chunk)
+        with jax.named_scope("ssd.chunk"):
+            y, final_state = ssd_chunked(
+                xs.reshape(b, s, h, pdim), dt, A, B.reshape(b, s, g, n),
+                C.reshape(b, s, g, n), cfg.ssm_chunk)
         y = y + p["D"][None, None, :, None].astype(y.dtype) \
             * xs.reshape(b, s, h, pdim)
         new_cache = None
@@ -230,12 +236,15 @@ def mixer_apply(p, cfg: ModelConfig, x, ctx: Optional[Ctx] = None,
             conv_state = jnp.moveaxis(xBC_raw[:, s - kc:, :], 1, 2)  # (B, C, K-1)
             new_cache = {"conv": conv_state, "ssm": final_state}
     else:
-        xBC_step, new_conv = conv_decode(cache["conv"], xBC[:, 0],
-                                         p["conv_w"], p["conv_b"])
+        with jax.named_scope("ssd.conv"):
+            xBC_step, new_conv = conv_decode(cache["conv"], xBC[:, 0],
+                                             p["conv_w"], p["conv_b"])
         xs, B, C = _split_xbc(cfg, xBC_step[:, None, :])
-        y1, new_ssm = ssd_decode(cache["ssm"], xs[:, 0].reshape(b, h, pdim),
-                                 dt[:, 0], A, B[:, 0].reshape(b, g, n),
-                                 C[:, 0].reshape(b, g, n))
+        with jax.named_scope("ssd.decode"):
+            y1, new_ssm = ssd_decode(cache["ssm"],
+                                     xs[:, 0].reshape(b, h, pdim), dt[:, 0], A,
+                                     B[:, 0].reshape(b, g, n),
+                                     C[:, 0].reshape(b, g, n))
         y = y1[:, None] + p["D"][None, None, :, None].astype(y1.dtype) \
             * xs.reshape(b, 1, h, pdim)
         new_cache = {"conv": new_conv, "ssm": new_ssm}

@@ -1,8 +1,15 @@
-"""Autoregressive rollout with KV cache: prefill + decode loop.
+"""Autoregressive rollout with a decode cache: prefill + decode loop.
 
 Used by the end-to-end examples and by the PlexRL ``generate`` service
 primitive. Sampling is temperature-based with greedy as temperature->0;
-returns behavior logprobs for importance-sampled objectives.
+returns behavior logprobs for importance-sampled objectives: each position's
+is the log-probability of the token it emits, which after a row's
+end-of-sequence token is ``eos_id`` and not the token drawn.
+
+The decode cache is whatever the model family keeps: a KV cache for
+attention, a conv window and SSM state for mamba2. With ``counters`` each
+rollout adds ``generate.state_bytes`` (the bytes of that cache, read from
+its shapes) and ``generate.decode_steps`` (the positions decoded).
 """
 from __future__ import annotations
 
@@ -42,7 +49,8 @@ def _pad_cache(cache, extra: int):
 def rollout(model: Model, params, prompt_tokens, rng,
             cfg: RolloutConfig = RolloutConfig(),
             ctx: Optional[Ctx] = None,
-            extra_inputs: Optional[Dict[str, Any]] = None
+            extra_inputs: Optional[Dict[str, Any]] = None,
+            counters: Optional[telemetry.Counters] = None
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Generate completions. prompt_tokens: (B, P) int32.
 
@@ -54,20 +62,22 @@ def rollout(model: Model, params, prompt_tokens, rng,
                                               return_cache=True)
         last_logits = last_logits[:, -1]
         cache = _pad_cache(cache, cfg.max_new_tokens)
+    if counters is not None:
+        counters.add("generate.state_bytes", sum(
+            x.size * x.dtype.itemsize for x in jax.tree.leaves(cache)))
+        counters.add("generate.decode_steps", cfg.max_new_tokens)
 
     def sample(logits, key):
-        logits = logits.astype(jnp.float32)
         if cfg.greedy or cfg.temperature <= 0:
-            tok = jnp.argmax(logits, axis=-1)
-        else:
-            tok = jax.random.categorical(key, logits / cfg.temperature, axis=-1)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return tok, jnp.take_along_axis(logp, tok[:, None], axis=-1)[:, 0]
+            return jnp.argmax(logits, axis=-1)
+        return jax.random.categorical(key, logits / cfg.temperature, axis=-1)
 
     def step(carry, key):
         logits, cache, alive = carry
-        tok, logp = sample(logits, key)
-        tok = jnp.where(alive, tok, cfg.eos_id)
+        logits = logits.astype(jnp.float32)
+        tok = jnp.where(alive, sample(logits, key), cfg.eos_id)
+        logp = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
+                                   tok[:, None], axis=-1)[:, 0]
         new_logits, new_cache = model.decode_step(params, cache,
                                                   {"tokens": tok[:, None]}, ctx)
         alive = alive & (tok != cfg.eos_id)

@@ -1,7 +1,9 @@
 """Compiles for a described TPU v5e chip (no chip attached): the three
 Pallas kernels at the widths of the configurations they serve, and the
-full-width qwen2-0.5b UPDATE_ACTOR step with its device-memory bound, and
-the state plane's offload copy into reused pinned host buffers.
+full-width qwen2-0.5b UPDATE_ACTOR step with its device-memory bound,
+mamba2-2.7b's UPDATE_ACTOR and GENERATE at the benchmark cell's cut (8
+layers, 16 rows of 256 positions), and the state plane's offload copy into
+reused pinned host buffers.
 
 What this guards that interpret mode cannot: block shapes the TPU compiler
 tiles, Mosaic lowering of the kernel bodies, and a train step that fits one
@@ -25,6 +27,7 @@ from repro.kernels import ssd as ssd_k
 from repro.launch.mesh import PRODUCTION_KIND, hw_peaks
 from repro.models.registry import build_model
 from repro.rl import grpo
+from repro.rl import rollout as rollout_lib
 from repro.train import optimizer as opt
 
 
@@ -92,26 +95,75 @@ def test_ssd_compiles_at_mamba2_width(sds):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_qwen2_update_actor_fits_one_chip(sds):
-    """The served UPDATE_ACTOR step at published widths (B=16, S=64):
-    arguments + outputs + temporaries stay under one chip's HBM."""
-    model = build_model(get_config("qwen2-0.5b"))
+def _on_chip(sds, tree):
+    return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+
+def _fits_one_chip(mem) -> int:
+    """Arguments + outputs + temporaries of a compiled program, asserted
+    under one chip's HBM."""
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < hw_peaks(PRODUCTION_KIND)["hbm_bytes"], total
+    return total
+
+
+def _update_actor_memory(sds, model, b, s):
+    """The served UPDATE_ACTOR step's memory analysis at B=b, S=s; the
+    donated moments alias their outputs."""
     adamw = opt.AdamWConfig()
-    on_chip = lambda t: jax.tree.map(lambda a: sds(a.shape, a.dtype), t)
-    params = on_chip(model.abstract_params())
-    opt_state = on_chip(opt.abstract_state(model.abstract_params(), adamw))
-    b, s = 16, 64
+    params = _on_chip(sds, model.abstract_params())
+    opt_state = _on_chip(sds, opt.abstract_state(model.abstract_params(),
+                                                  adamw))
     batch = {"tokens": sds((b, s), jnp.int32),
              "behavior_logprobs": sds((b, s), jnp.float32),
              "advantages": sds((b,), jnp.float32),
              "loss_mask": sds((b, s), jnp.float32)}
     step = jit_update_actor(model, grpo.GRPOConfig(), adamw)
     mem = step.lower(params, opt_state, batch).compile().memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes)
-    assert total < hw_peaks(PRODUCTION_KIND)["hbm_bytes"], total
-    # the donated moments alias their outputs
-    assert mem.alias_size_in_bytes > 0
+    _fits_one_chip(mem)
+    moments = 2 * sum(x.size * 4 for x in
+                      jax.tree.leaves(model.abstract_params()))
+    assert mem.alias_size_in_bytes >= moments, (mem.alias_size_in_bytes,
+                                                moments)
+    return mem
+
+
+def test_qwen2_update_actor_fits_one_chip(sds):
+    """The served UPDATE_ACTOR step at published widths (B=16, S=64):
+    arguments + outputs + temporaries stay under one chip's HBM."""
+    _update_actor_memory(sds, build_model(get_config("qwen2-0.5b")), 16, 64)
+
+
+def _mamba2_cell_model():
+    """mamba2-2.7b as the benchmark cell runs it: 8 of its 64 layers."""
+    return build_model(get_config("mamba2-2.7b").replace(num_layers=8))
+
+
+def test_mamba2_update_actor_fits_one_chip(sds):
+    """mamba2-2.7b's served UPDATE_ACTOR at the cell's cut (B=16, S=256,
+    one SSD chunk of 256): 4.50 GB of train state, the new params and the
+    chunked scan's temporaries fit one chip."""
+    mem = _update_actor_memory(sds, _mamba2_cell_model(), 16, 256)
+    assert mem.argument_size_in_bytes > 4.5e9
+
+
+def test_mamba2_generate_fits_one_chip(sds):
+    """GENERATE at the cell's sizes (16 rows, 64 prompt + 192 new tokens)
+    as one program: the prefill and the decode loop, which carries each
+    row's conv window and f32 SSM state through its 192 steps."""
+    model = _mamba2_cell_model()
+    cfg = rollout_lib.RolloutConfig(max_new_tokens=192)
+    gen = jax.jit(lambda p, prompts, key: rollout_lib.rollout(
+        model, p, prompts, key, cfg))
+    mem = gen.lower(_on_chip(sds, model.abstract_params()),
+                    sds((16, 64), jnp.int32),
+                    sds((2,), jnp.uint32)).compile().memory_analysis()
+    _fits_one_chip(mem)
+    state = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(model.abstract_cache(16, 256)))
+    assert state > 3.3e8                        # 8 x 16 x 80 x 64 x 128 x 4 B
+    assert mem.temp_size_in_bytes >= state
 
 
 def test_offload_copies_land_in_the_donated_pinned_buffers(topo, sds):

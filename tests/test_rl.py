@@ -13,10 +13,15 @@ from repro.models.registry import build_model
 from repro.rl import data, grpo, reward, rollout
 
 
-def _tiny_model():
-    cfg = ModelConfig(name="tiny", family="dense", num_layers=2, d_model=32,
-                      num_heads=4, num_kv_heads=2, head_dim=8, d_ff=64,
-                      vocab_size=64, tie_embeddings=True)
+def _tiny_model(family: str = "dense"):
+    if family == "ssm":
+        cfg = ModelConfig(name="tiny-ssm", family="ssm", num_layers=2,
+                          d_model=32, vocab_size=64, ssm_state=8,
+                          ssm_head_dim=8, ssm_chunk=4, tie_embeddings=True)
+    else:
+        cfg = ModelConfig(name="tiny", family="dense", num_layers=2,
+                          d_model=32, num_heads=4, num_kv_heads=2, head_dim=8,
+                          d_ff=64, vocab_size=64, tie_embeddings=True)
     m = build_model(cfg)
     return m, m.init_params(jax.random.PRNGKey(0))
 
@@ -95,6 +100,49 @@ def test_rollout_shapes_and_greedy_determinism():
     assert t1.shape == (2, 5) and l1.shape == (2, 5)
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))  # greedy
     assert bool((l1 <= 0).all())
+
+
+@pytest.mark.parametrize("family", ["dense", "ssm"])
+def test_rollout_logprob_is_the_emitted_tokens_after_eos(family):
+    """A row's end-of-sequence token comes early: every position records
+    the log-probability of the token it emits (``eos_id`` once the row is
+    done), read off that step's logits, which a prefill and a decode fed
+    the emitted tokens give again."""
+    m, params = _tiny_model(family)
+    prompts = jax.random.randint(jax.random.PRNGKey(3), (3, 6), 3, 64)
+    key, n = jax.random.PRNGKey(4), 8
+    drawn, _, _ = rollout.rollout(m, params, prompts, key,
+                                  rollout.RolloutConfig(max_new_tokens=n))
+    eos = int(drawn[0, 1])              # row 0 draws it second
+    toks, logps, _ = rollout.rollout(
+        m, params, prompts, key,
+        rollout.RolloutConfig(max_new_tokens=n, eos_id=eos))
+    toks = np.asarray(toks)
+    # tokens up to and including the first end-of-sequence are as drawn
+    np.testing.assert_array_equal(toks[0, :2], np.asarray(drawn)[0, :2])
+    assert (toks[0, 2:] == eos).all()
+    assert (np.asarray(drawn)[0, 2:] != eos).any()   # the fix is exercised
+    logits, _, cache = m.forward(params, {"tokens": prompts},
+                                 return_cache=True)
+    cache = rollout._pad_cache(cache, n)
+    step_logits, want = logits[:, -1], []
+    for t in range(n):
+        lp = jax.nn.log_softmax(step_logits.astype(jnp.float32), axis=-1)
+        want.append(np.take_along_axis(np.asarray(lp), toks[:, t:t + 1],
+                                       axis=-1)[:, 0])
+        out, cache = m.decode_step(params, cache,
+                                   {"tokens": jnp.asarray(toks[:, t:t + 1])})
+        step_logits = out[:, -1]
+    np.testing.assert_allclose(np.asarray(logps), np.stack(want, axis=1),
+                               atol=1e-3)
+    # so GRPO's importance ratio is 1 on every trained position, the
+    # policy's own: the k3 estimate of KL(behaviour || policy) is 0
+    batch = data.pack_rollout_batch(np.asarray(prompts), toks,
+                                    np.asarray(logps), np.ones(3, np.float32),
+                                    group_size=3, seq_len=6 + n)
+    _, metrics = grpo.grpo_loss(params, m, jax.tree.map(jnp.asarray, batch),
+                                grpo.GRPOConfig())
+    assert float(metrics["kl"]) < 1e-5, metrics["kl"]
 
 
 # ------------------------------------------------------------------ reward

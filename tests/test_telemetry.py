@@ -24,6 +24,8 @@ WAIT_S = 30.0          # bound on waiting for the watcher thread
 TINY = (("num_layers", 2), ("d_model", 32), ("num_heads", 4),
         ("num_kv_heads", 2), ("head_dim", 8), ("d_ff", 64),
         ("vocab_size", 64), ("tie_embeddings", True))
+TINY_SSM = (("num_layers", 2), ("d_model", 32), ("vocab_size", 64),
+            ("ssm_state", 8), ("ssm_head_dim", 8), ("ssm_chunk", 4))
 
 
 class ClockStub:
@@ -187,6 +189,28 @@ def test_switch_counters_move_the_jobs_bytes(tmp_path):
     assert wait_for(router.telemetry, "switch.load_s") >= 0.0
     # a switch the benchmark counts is one that offloaded a job
     assert sum(1 for e in router.switch_log if e["t_offload"] > 0) == 2
+
+
+@pytest.mark.parametrize("model,overrides", [("qwen2-0.5b", TINY),
+                                              ("mamba2-2.7b", TINY_SSM)])
+def test_generate_counts_its_decode_state_and_steps(model, overrides):
+    """One GENERATE on the served path adds the bytes of the decode cache
+    it carries through its loop (a KV cache over prompt and completion;
+    a conv window and SSM state) and the positions it decodes."""
+    router = Router()
+    spec = api.DeploymentSpec(deployment_id="d0", job_id="j0",
+                              model_name=model, role="train",
+                              overrides=overrides)
+    dep = router.deploy(spec, group_id=0)
+    dep.init(seed=0)
+    rows, prompt, new = 3, 5, 6
+    dep.generate(np.full((rows, prompt), 7, np.int32), max_new_tokens=new)
+    router.drain()
+    snap = router.telemetry.snapshot()
+    cache = dep.wpg.model.abstract_cache(rows, prompt + new)
+    assert snap["generate.state_bytes"] == sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert snap["generate.decode_steps"] == new
 
 
 class GatedLeaf:
