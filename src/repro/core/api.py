@@ -17,9 +17,11 @@ operation each and return a chainable :class:`Future`.
   results.
 - Any :class:`Future` passed as an operation *argument* is a dataflow edge:
   the futures' source operations are registered as prerequisites
-  automatically, the Router holds the op until they settle, and the resolved
-  values are spliced into the arguments at dispatch time. No manual
-  ``req_id`` wiring, no nested callbacks.
+  automatically, the Router holds the op until they have completed AND the
+  futures themselves have resolved (a ``.then`` transform may still be
+  running after its source op finished), and the resolved values are
+  spliced into the arguments at dispatch time. No manual ``req_id``
+  wiring, no nested callbacks.
 
 ``make_op`` + ``Router.submit_queued_operation`` remain the low-level
 escape hatch underneath (explicit req_id prerequisites, custom arrival
@@ -82,10 +84,10 @@ class Future:
 
     ``sources`` is the dataflow provenance: the req_ids of the operations
     this value (transitively) derives from. Submitting a future as an op
-    argument turns its sources into scheduler prerequisites, so by the time
-    the dependent op is admitted the future is resolved (or about to be, in
-    the narrow window between a source op's COMPLETED transition and its
-    callback chain firing — dispatch bridges that with a bounded wait).
+    argument turns its sources into scheduler prerequisites and, while it
+    is unresolved, gates the dependent op on the future itself, so the
+    dependent op is admitted only once the future has resolved: dispatch
+    never waits on it.
     """
 
     __slots__ = ("_cond", "_done", "_result", "_error", "_callbacks",
@@ -154,7 +156,8 @@ class Future:
         If this future errors, the error propagates and ``fn`` never runs;
         if ``fn`` raises, the derived future carries that error. The derived
         future inherits this future's dataflow sources, so it can itself be
-        passed as an op argument (the Router gates on the same source ops).
+        passed as an op argument (the Router gates on the same source ops,
+        and on the derived future itself).
         """
         child = Future(sources=self.sources)
 
@@ -224,12 +227,10 @@ _req_counter = itertools.count(1)
 # otherwise dispatch would block on an ungated future.
 _MAX_ARG_DEPTH = 3
 
-# Upper bound on the dispatch-time wait for a future argument whose source
-# ops already COMPLETED: it covers the client-side `.then` transform chain
-# still running on the resolving thread (packing a large rollout batch can
-# take real time), NOT the ops themselves — those are gated by
-# prerequisites. Module-level so deployments with pathological transforms
-# can raise it.
+# Upper bound on the dispatch-time wait for a future argument. The Router
+# admits an op only once every future it takes as an argument has resolved
+# (the argument gate), so the splice finds them resolved and this wait
+# never blocks; it stays as a bound on code that splices outside that path.
 SPLICE_TIMEOUT_S = 600.0
 
 
@@ -253,11 +254,10 @@ def _walk_futures(obj, found: List[Future], depth: int = 0):
 
 def _splice(obj, depth: int = 0, timeout: Optional[float] = None):
     """Replace embedded futures with their resolved values (dispatch-time
-    argument substitution; mirrors :func:`_walk_futures`). The futures'
-    source ops are COMPLETED by the time the dependent op is dispatched, so
-    the bounded wait only bridges the instant between a source's state
-    transition and its callback chain; a future that errored re-raises
-    here, failing (and thus poisoning) the dependent op."""
+    argument substitution; mirrors :func:`_walk_futures`). The argument
+    gate admits the dependent op only once its futures resolved, so the
+    bounded wait returns at once; a future that errored re-raises here,
+    failing (and thus poisoning) the dependent op."""
     if isinstance(obj, Future):
         return obj.wait(timeout=SPLICE_TIMEOUT_S if timeout is None
                         else timeout)
@@ -285,7 +285,8 @@ class QueuedOperation:
     arrival_time: float = 0.0
     future: Future = dataclasses.field(default_factory=Future)
     prerequisites: Tuple[int, ...] = ()
-    has_future_args: bool = False
+    # the futures embedded in args/kwargs, until resolve_args splices them
+    arg_futures: Tuple[Future, ...] = ()
     # set by Router.teardown for an op already RUNNING when its deployment
     # detaches: the execution backend, pinned so the op still completes
     # normally after the router's wpg table entry is gone
@@ -295,11 +296,11 @@ class QueuedOperation:
         """Dispatch-time dataflow splice: substitute resolved values for any
         future passed as an argument. Mutates in place (each op dispatches
         exactly once)."""
-        if not self.has_future_args:
+        if not self.arg_futures:
             return
         self.args = tuple(_splice(v) for v in self.args)
         self.kwargs = {k: _splice(v) for k, v in self.kwargs.items()}
-        self.has_future_args = False
+        self.arg_futures = ()
 
 
 def make_op(deployment: DeploymentSpec, op: Op, *args,
@@ -330,8 +331,8 @@ def make_op(deployment: DeploymentSpec, op: Op, *args,
             prereqs.append(p)
     for f in embedded:
         if not f.sources and not f.done():
-            # no prerequisite can gate this op, so dispatch would block a
-            # group's exclusive lock waiting on a hand-made future — refuse
+            # no operation of the plane will resolve a hand-made future, so
+            # the op could wait on its argument gate forever — refuse
             # loudly at submit time instead
             raise ValueError(
                 "argument future has no source operations and is "
@@ -348,7 +349,7 @@ def make_op(deployment: DeploymentSpec, op: Op, *args,
         exec_estimate=exec_estimate,
         arrival_time=arrival_time,
         prerequisites=tuple(prereqs),
-        has_future_args=bool(embedded),
+        arg_futures=tuple(embedded),
     )
     qop.future.sources = (req_id,)
     return qop

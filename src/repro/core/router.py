@@ -30,9 +30,21 @@ wrappers:
 
 Dataflow arguments: an operation whose arguments embed unresolved
 :class:`~repro.core.api.Future`\\ s is held by its auto-registered
-prerequisites and the resolved values are spliced in at dispatch time
-(``QueuedOperation.resolve_args``), so client code chains ops without
-manual req_id wiring.
+prerequisites and by an argument gate on the futures themselves
+(``TaskExecutor.open_arg_gate``): it is admitted only once each has
+resolved, so the values spliced in at dispatch time
+(``QueuedOperation.resolve_args``) are there already and a dispatch thread
+never waits on one. Client code chains ops without manual req_id wiring.
+
+Client callbacks: under the threaded drivers (:meth:`serve`,
+:meth:`run_until_idle`) a finished op's future is resolved on its job's
+callback worker (one FIFO daemon thread per job, ``callbacks-<job>``), so
+a slow callback — a controller packing and grading a rollout — holds no
+group's dispatch thread: the group admits another job's op meanwhile. Each
+job's callbacks fire in the order its ops finished. A queued resolution
+counts as in flight until it has fired, so no driver declares the plane
+idle while a callback may still submit work. The serial driver fires
+callbacks inline, on its own thread.
 
 Failure propagation: an operation that raises resolves its future with the
 error, and any queued operation whose prerequisite FAILED is itself failed
@@ -42,6 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import queue
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -56,6 +69,30 @@ from repro.launch.proc_plane import (GroupProcess, StateManagerProxy,
                                      WPGProxy)
 
 logger = logging.getLogger(__name__)
+
+
+class _CallbackWorker:
+    """One job's future resolutions, fired in the order they were queued
+    on a daemon thread of its own. :meth:`stop` lets it fire what it holds,
+    then exit."""
+
+    def __init__(self, name: str, fire: Callable[..., None]):
+        self._queue: queue.SimpleQueue = queue.SimpleQueue()
+        self.thread = threading.Thread(target=self._run, args=(fire,),
+                                       name=name, daemon=True)
+        self.thread.start()
+
+    def put(self, item: tuple):
+        self._queue.put(item)
+
+    def stop(self):
+        self._queue.put(None)
+
+    def _run(self, fire: Callable[..., None]):
+        while (item := self._queue.get()) is not None:
+            fire(*item)
+            # drop the op's result before parking: it may hold a rollout
+            del item
 
 
 class Router:
@@ -136,8 +173,13 @@ class Router:
         self.switch_log: List[dict] = []
         self.wpg_factory = wpg_factory
         # exceptions raised by user callbacks during future resolution; a
-        # broken callback must not kill a dispatch thread mid-protocol
+        # broken callback must not kill a dispatch or callback thread
         self.callback_errors: List[Tuple[int, BaseException]] = []
+        # per-job callback workers while a threaded driver runs (None: the
+        # serial driver's inline firing), and, per group, the jobs whose
+        # callbacks are firing now (dispatch.admitted_in_callbacks)
+        self._cb_workers: Optional[Dict[str, _CallbackWorker]] = None
+        self._cb_running: Dict[int, Dict[str, int]] = {}
         # persistent serve-mode plane: one stop token per group worker (so
         # retire_group can tear one down) plus the plane-wide shutdown token
         self._serving = False
@@ -233,10 +275,11 @@ class Router:
         once its last deployment detaches."""
         cancelled: List[Tuple[api.QueuedOperation, Exception]] = []
         ex = self.executor
+        last_of_job = False
         with ex.cv:
             wpg = self.wpgs.pop(deployment_id, None)
             spec = self.deployments.pop(deployment_id, None)
-            self.group_of.pop(deployment_id, None)
+            group = self.group_of.pop(deployment_id, None)
             if spec is not None:
                 err = RuntimeError(
                     f"deployment {deployment_id} torn down")
@@ -252,9 +295,11 @@ class Router:
                         continue
                     self.executor.finish(task, error=str(err))
                     self._finalize(qop)
-                    cancelled.append((qop, err))
+                    if not self._defer(group, qop, None, err):
+                        cancelled.append((qop, err))
                 if not any(s.job_id == spec.job_id
                            for s in self.deployments.values()):
+                    last_of_job = True
                     self.request_queues.pop(spec.job_id, None)
                     self.job_priority.pop(spec.job_id, None)
                     self.job_tenant.pop(spec.job_id, None)
@@ -285,6 +330,10 @@ class Router:
                 with ex.cv:
                     ex.inflight -= 1
                     ex.cv.notify_all()
+        if last_of_job:
+            # the job's ops are all settled: its worker fires what it
+            # holds (the cancellations above among them) and exits
+            self._stop_callback_workers(spec.job_id, timeout=120.0)
 
     # -------------------------------------------------------------- submit
     def register_job_tenant(self, job_id: str, tenant_id: str,
@@ -304,7 +353,8 @@ class Router:
         Thread-safe: future callbacks submit follow-up operations from
         dispatch worker threads while controllers submit from client
         threads; a live serve plane admits the op the moment its group and
-        prerequisites allow."""
+        prerequisites allow. An unresolved future among its arguments gates
+        it until that future resolves (``TaskExecutor.open_arg_gate``)."""
         with self.executor.cv:
             if qop.deployment_id not in self.group_of:
                 raise RuntimeError(
@@ -318,9 +368,16 @@ class Router:
                                priority=self.job_priority.get(
                                    qop.job_id, 1.0))
             group = self.group_of[qop.deployment_id]
+            unresolved = [f for f in qop.arg_futures if not f.done()]
             self.executor.submit(req, group,
-                                 prerequisites=qop.prerequisites)
+                                 prerequisites=qop.prerequisites,
+                                 arg_gates=len(unresolved))
             self.pending[qop.req_id] = qop
+            for f in unresolved:
+                # fires at once, here, if it resolved since the check
+                f.add_done_callback(
+                    lambda _f, req_id=qop.req_id:
+                        self.executor.open_arg_gate(req_id))
         return qop.future
 
     # ------------------------------------------------------------ dispatch
@@ -360,8 +417,9 @@ class Router:
     def _resolve_future(self, qop: api.QueuedOperation, result,
                         err: Optional[BaseException]):
         """Resolve the op's future, firing its client callbacks on this
-        thread. On a dispatch thread its group admits nothing meanwhile:
-        that time is counted as ``dispatch.callback_s``."""
+        thread — the job's callback worker under the threaded drivers, the
+        driver's own thread under the serial one — and count the time as
+        ``dispatch.callback_s``."""
         t0 = self.now()
         try:
             with telemetry.span("callbacks", job=qop.job_id, req=qop.req_id):
@@ -374,6 +432,72 @@ class Router:
                            qop.req_id, cb_err)
             self.callback_errors.append((qop.req_id, cb_err))
         self.telemetry.add("dispatch.callback_s", self.now() - t0)
+
+    def _defer(self, group_id: Optional[int], qop: api.QueuedOperation,
+               result, err: Optional[BaseException]) -> bool:
+        """Queue the op's resolution on its job's callback worker, started
+        on first use, and return True; False where no threaded driver runs
+        (the caller then resolves inline, outside the lock). Must hold
+        executor.cv. The queued resolution counts as in flight until it has
+        fired."""
+        workers = self._cb_workers
+        if workers is None:
+            return False
+        w = workers.get(qop.job_id)
+        if w is None:
+            w = workers[qop.job_id] = _CallbackWorker(
+                f"callbacks-{qop.job_id}", self._fire_deferred)
+        self.executor.inflight += 1
+        w.put((group_id, qop, result, err))
+        return True
+
+    def _fire_deferred(self, group_id: Optional[int],
+                       qop: api.QueuedOperation, result,
+                       err: Optional[BaseException]):
+        """A callback worker's firing of one queued resolution."""
+        ex = self.executor
+        with ex.cv:
+            jobs = self._cb_running.setdefault(group_id, {})
+            jobs[qop.job_id] = jobs.get(qop.job_id, 0) + 1
+        try:
+            self._resolve_future(qop, result, err)
+        finally:
+            with ex.cv:
+                jobs = self._cb_running[group_id]
+                jobs[qop.job_id] -= 1
+                if not jobs[qop.job_id]:
+                    del jobs[qop.job_id]
+                ex.inflight -= 1
+                ex.cv.notify_all()
+
+    def _stop_callback_workers(self, job_id: Optional[str] = None,
+                               timeout: Optional[float] = None):
+        """Stop one job's callback worker, or every one (back to inline
+        firing). Each fires what it holds, then exits; one still firing
+        after ``timeout`` is abandoned as a daemon and reported."""
+        with self.executor.cv:
+            if self._cb_workers is None:
+                return
+            if job_id is None:
+                workers = list(self._cb_workers.values())
+                self._cb_workers = None
+            else:
+                w = self._cb_workers.pop(job_id, None)
+                workers = [] if w is None else [w]
+        deadline = None if timeout is None else time.monotonic() + timeout
+        for w in workers:
+            w.stop()
+        for w in workers:
+            if w.thread is threading.current_thread():
+                continue              # stopped from its own callback
+            w.thread.join(None if deadline is None
+                          else max(0.0, deadline - time.monotonic()))
+            if w.thread.is_alive():
+                with self.executor.cv:
+                    self._abandoned.append(w.thread)
+                logger.warning("callback worker %s still firing at its "
+                               "stop; abandoned as a daemon (see "
+                               "abandoned_workers())", w.thread.name)
 
     def _raise_callback_errors(self, since: int):
         """Drivers fail loudly at exit if any user callback raised during
@@ -397,11 +521,13 @@ class Router:
         if queue is not None:
             queue.pop(qop.req_id, None)
 
-    def _reap_poisoned(self) -> List[Tuple[api.QueuedOperation, Exception]]:
+    def _reap_poisoned(self) -> List[Tuple[int, api.QueuedOperation,
+                                           Exception]]:
         """FAIL every queued task whose prerequisite FAILED (to fixpoint, so
         chains of dependents collapse in one call). Returns the affected
-        (qop, error) pairs; callers fire the futures OUTSIDE the lock."""
-        out: List[Tuple[api.QueuedOperation, Exception]] = []
+        (group, qop, error) triples; callers fire the futures OUTSIDE the
+        lock."""
+        out: List[Tuple[int, api.QueuedOperation, Exception]] = []
         with self.executor.cv:
             # fast path: the full-table scan below is only worth paying
             # after a failure EVENT (a FAILED transition, or a submission
@@ -426,7 +552,7 @@ class Router:
                     qop = self.pending.get(t.request.req_id)
                     if qop is not None:
                         self._finalize(qop)
-                        out.append((qop, err))
+                        out.append((t.group_id, qop, err))
                     changed = True
             # fixpoint reached under the lock: nothing QUEUED has a failed
             # prereq until the next failure event sets the flag again
@@ -442,7 +568,8 @@ class Router:
         and exit before the callback's resubmission arrives."""
         ex = self.executor
         with ex.cv:
-            poisoned = self._reap_poisoned()
+            poisoned = [(qop, err) for g, qop, err in self._reap_poisoned()
+                        if not self._defer(g, qop, None, err)]
             if not poisoned:
                 return
             ex.inflight += 1
@@ -457,8 +584,10 @@ class Router:
     def _execute_admitted(self, group_id: int, task: Task) -> None:
         """Run one admitted (RUNNING) operation to completion and resolve its
         future. Shared by the serial driver and the per-group dispatch
-        threads; the future is resolved OUTSIDE the executor lock so
-        callbacks may submit follow-up operations."""
+        threads. A dispatch thread hands the resolution to the job's
+        callback worker and returns to admission at once; the serial driver
+        resolves here, OUTSIDE the executor lock, so callbacks may submit
+        follow-up operations."""
         with self.executor.cv:
             qop = self.pending[task.request.req_id]
             # an op RUNNING when its deployment tore down keeps executing on
@@ -467,7 +596,7 @@ class Router:
         result, err = None, None
         try:
             # dataflow splice: substitute resolved values for future args
-            # (their source ops COMPLETED before this op became admissible)
+            # (the argument gate admitted the op once they had resolved)
             qop.resolve_args()
             if wpg is None:
                 raise RuntimeError(
@@ -481,7 +610,9 @@ class Router:
             self.executor.finish(task, error=None if err is None
                                  else str(err))
             self._finalize(qop)
-        self._resolve_future(qop, result, err)
+            deferred = self._defer(group_id, qop, result, err)
+        if not deferred:
+            self._resolve_future(qop, result, err)
 
     def _execute_timed(self, qop: api.QueuedOperation, wpg):
         """``wpg.execute`` in a ``plexrl.op.<OP>`` span, counted as
@@ -561,6 +692,10 @@ class Router:
                 if t is not None and ex.try_start(t):
                     ex.inflight += 1
                     task = t
+                    if any(j != t.request.job_id
+                           for j in self._cb_running.get(group_id, ())):
+                        # the verifier stall of another job, filled
+                        self.telemetry.add("dispatch.admitted_in_callbacks")
                 elif (not persistent and ex.outstanding() == 0
                         and ex.inflight == 0):
                     ex.cv.notify_all()
@@ -616,6 +751,7 @@ class Router:
             self._serve_executed = {}
             self._serve_err_start = len(self.callback_errors)
             self._serving = True
+            self._cb_workers = {}
             groups = sorted(set(self.group_of.values()))
         for g in groups:
             self._ensure_serve_worker(g)
@@ -623,8 +759,9 @@ class Router:
     def shutdown(self, timeout: Optional[float] = None):
         """Stop the serve plane: parked workers exit immediately; a worker
         mid-execute finishes its operation first (bounded by ``timeout`` if
-        given, after which it is abandoned as a daemon). Raises at the end
-        if any user callback raised while serving."""
+        given, after which it is abandoned as a daemon). The callback
+        workers then fire what they hold and exit. Raises at the end if any
+        user callback raised while serving."""
         if not self._serving:
             return
         self._serve_stop.set()
@@ -637,6 +774,9 @@ class Router:
             t.join(timeout=None if deadline is None
                    else max(0.0, deadline - time.monotonic()))
         leaked = [t for t in self._serve_threads.values() if t.is_alive()]
+        self._stop_callback_workers(
+            timeout=None if deadline is None
+            else max(0.0, deadline - time.monotonic()))
         with self.executor.cv:
             self._serving = False
             self._serve_threads = {}
@@ -987,6 +1127,8 @@ class Router:
         executed = [0] * len(groups)
         timed_out = threading.Event()
         ex = self.executor
+        with ex.cv:
+            self._cb_workers = {}
 
         threads = [threading.Thread(
             target=self._worker_loop,
@@ -1026,6 +1168,9 @@ class Router:
                         "abandon grace; leaked as a daemon (see "
                         "abandoned_workers())", t.name)
                 break
+        self._stop_callback_workers(
+            timeout=None if deadline is None
+            else max(0.0, deadline + 1.0 - time.monotonic()))
         if timed_out.is_set():
             with ex.cv:
                 stuck = [t.request.req_id for t in ex.tasks.values()

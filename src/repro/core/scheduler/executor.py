@@ -11,7 +11,9 @@ A lightweight finite state machine per job/request with three mechanics:
   unchanged Algorithm-1 oracle the index is property-tested against (and
   the path non-``hrrs`` policies use).
 - Lock-Gated Execution (RUNNING): a request transitions to RUNNING only
-  after prerequisites finish and the exclusive node-group lock is acquired.
+  after prerequisites finish, its argument gates open (the futures it takes
+  as arguments resolved: :meth:`TaskExecutor.open_arg_gate`), and the
+  exclusive node-group lock is acquired.
 - Lifecycle Teardown (COMPLETED): releases locks and unblocks successors.
 
 The executor is time-source agnostic: a callable ``now()`` lets the SAME
@@ -48,8 +50,10 @@ class Task:
     group_id: int
     state: State = State.QUEUED
     prerequisites: tuple = ()          # req_ids that must COMPLETE first
+    arg_gates: int = 0                 # argument futures not yet resolved
     t_admitted: float = 0.0
-    t_ready: float = 0.0               # when its last prerequisite completed
+    t_ready: float = 0.0               # when it last became admissible:
+    #   its last prerequisite completed, or its last argument resolved
     t_started: float = 0.0
     t_finished: float = 0.0
     error: Optional[str] = None
@@ -218,10 +222,13 @@ class TaskExecutor:
 
     # ------------------------------------------------------------- submit
     def submit(self, request: hrrs.Request, group_id: int,
-               prerequisites: Sequence[int] = ()) -> Task:
+               prerequisites: Sequence[int] = (), arg_gates: int = 0) -> Task:
+        """Enqueue a request. ``arg_gates`` counts the unresolved futures it
+        takes as arguments: it is not admissible until each has called
+        :meth:`open_arg_gate`, whatever the state of its prerequisites."""
         with self.cv:
             t = Task(request=request, group_id=group_id,
-                     prerequisites=tuple(prerequisites),
+                     prerequisites=tuple(prerequisites), arg_gates=arg_gates,
                      t_admitted=self.now())
             self.tasks[request.req_id] = t
             self.locks.setdefault(group_id, GroupLock())
@@ -261,8 +268,29 @@ class TaskExecutor:
 
     def _ready(self, t: Task) -> bool:
         return (t.state == State.QUEUED
+                and t.arg_gates == 0
                 and t.request.job_id not in self.held_jobs
                 and self._prereqs_done(t))
+
+    def open_arg_gate(self, req_id: int):
+        """One of the task's argument futures resolved. When the last one
+        has, the task is admissible as soon as its prerequisites are done:
+        its ``t_ready`` moves to now, and the time it waited on its
+        arguments alone counts as ``dispatch.arg_gate_s``."""
+        with self.cv:
+            t = self.tasks.get(req_id)
+            if t is None or t.state != State.QUEUED or t.arg_gates <= 0:
+                return
+            t.arg_gates -= 1
+            if t.arg_gates:
+                return
+            if self._prereqs_done(t):
+                now = self.now()
+                self.telemetry.add("dispatch.arg_gate_s", now - t.t_ready)
+                t.t_ready = now
+            if self.use_admission_index and self._ready(t):
+                self._index_insert(t)
+            self.cv.notify_all()
 
     def failed_prereqs(self, t: Task) -> List[int]:
         return [p for p in t.prerequisites

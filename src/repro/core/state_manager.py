@@ -45,6 +45,7 @@ import enum
 import functools
 import logging
 import os
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -109,6 +110,15 @@ def _to_host(srcs: List[jax.Array], slots: List[Any]) -> List[jax.Array]:
     return jax.block_until_ready(out)
 
 
+def _locked(method):
+    """Run the method under the manager's lock (see ``StateManager``)."""
+    @functools.wraps(method)
+    def run(self, *args, **kwargs):
+        with self._lock:
+            return method(self, *args, **kwargs)
+    return run
+
+
 def _spec_divides(shape, spec, mesh) -> bool:
     """Whether every dim of ``shape`` divides by the product of the mesh
     axes ``spec`` assigns to it (what a sharded ``device_put`` requires)."""
@@ -124,7 +134,14 @@ def _spec_divides(shape, spec, mesh) -> bool:
 
 
 class StateManager:
-    """One instance per node. Owns every byte of managed model state."""
+    """One instance per node. Owns every byte of managed model state.
+
+    One lock serialises what adds, drops, moves or reads entries
+    (``register``, ``reregister``, ``unregister``, ``offload``,
+    ``prefetch``, eviction, ``gather``, ``keys_for``, ``job_bytes``): a job's
+    client callback may gather its state while the group's dispatch thread
+    switches or updates another job, and sees each entry either before a
+    move or after it."""
 
     def __init__(self, node_id: str = "node0",
                  device_capacity: float = float("inf"),
@@ -142,6 +159,7 @@ class StateManager:
         # live on these devices; None = wherever jax defaults (legacy view)
         self.mesh_slice = mesh_slice
         self.entries: Dict[str, Entry] = {}
+        self._lock = threading.RLock()
         self.telemetry = telemetry
         # pinned-host buffers loads came from, by key, for the key's next
         # offload to land in; not counted in HOST, dropped with the job's
@@ -207,6 +225,7 @@ class StateManager:
         return jnp.asarray(arr)
 
     # ----------------------------------------------------------- register
+    @_locked
     def register(self, job_id: str, tree, tier: Tier = Tier.DEVICE,
                  prefix: str = "params") -> List[str]:
         """Adopt a pytree of tensors under canonical keys. Re-registering an
@@ -227,10 +246,21 @@ class StateManager:
         self._evict_if_needed()
         return keys
 
+    @_locked
+    def reregister(self, job_id: str, tree, tier: Tier = Tier.DEVICE,
+                   prefix: str = "params") -> List[str]:
+        """Replace the job's keys under ``prefix`` with ``tree`` in one step
+        (unregister, then :meth:`register`): a concurrent :meth:`gather`
+        sees the old tree or the new one, never a part of either."""
+        self.unregister(self.keys_for(job_id, prefix))
+        return self.register(job_id, tree, tier, prefix)
+
+    @_locked
     def keys_for(self, job_id: str, prefix: Optional[str] = None) -> List[str]:
         pre = f"{job_id}/" + (f"{prefix}/" if prefix else "")
         return [k for k in self.entries if k.startswith(pre)]
 
+    @_locked
     def unregister(self, keys: Sequence[str]):
         jobs = set()
         for k in keys:
@@ -261,6 +291,7 @@ class StateManager:
         return s
 
     # ------------------------------------------------------ tier movement
+    @_locked
     def offload(self, keys: Sequence[str], to: Tier = Tier.HOST) -> float:
         """Move state down the hierarchy. Returns elapsed seconds.
 
@@ -311,6 +342,7 @@ class StateManager:
             self.telemetry.add("switch.offload_leaves", leaves)
         return dt
 
+    @_locked
     def prefetch(self, keys: Sequence[str], shardings=None) -> float:
         """Move state up to DEVICE (scheduler-directed prefetch). Timed via
         ``self.clock`` for the same determinism contract as offload."""
@@ -359,6 +391,7 @@ class StateManager:
         self._evict_if_needed()
         return dt
 
+    @_locked
     def _evict_if_needed(self):
         """Capacity-aware LRU eviction DEVICE->HOST->DISK."""
         while self._tier_bytes(Tier.DEVICE) > self.device_capacity:
@@ -377,11 +410,13 @@ class StateManager:
     def offload_time_estimate(self, nbytes: int) -> float:
         return self._estimate("offload", nbytes, 1e10)
 
+    @_locked
     def job_bytes(self, job_id: str) -> int:
         return sum(e.nbytes for k, e in self.entries.items()
                    if k.startswith(f"{job_id}/"))
 
     # ------------------------------------------------------- gather trees
+    @_locked
     def gather(self, job_id: str, template, prefix: str = "params"):
         """Rebuild a pytree from managed entries (any tier; loads lazily from
         disk, gives host tensors as numpy)."""

@@ -184,14 +184,10 @@ class WorkerProcessGroup:
 
     def _store(self, params=None, opt_state=None):
         if params is not None:
-            for k in self.sm.keys_for(self.job_prefix, "params"):
-                self.sm.unregister([k])
-            self._keys["params"] = self.sm.register(
+            self._keys["params"] = self.sm.reregister(
                 self.job_prefix, params, Tier.DEVICE, "params")
         if opt_state is not None:
-            for k in self.sm.keys_for(self.job_prefix, "opt"):
-                self.sm.unregister([k])
-            self._keys["opt"] = self.sm.register(
+            self._keys["opt"] = self.sm.reregister(
                 self.job_prefix, opt_state, Tier.DEVICE, "opt")
 
     def resident(self) -> bool:

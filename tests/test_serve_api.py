@@ -390,3 +390,186 @@ def test_serve_body_exception_detaches_clients_and_stays_clean():
         time.sleep(0.2)
     assert c.controllers["longjob"].steps_completed == steps_before
     assert not _serve_threads()
+
+
+# ------------------------------------- callbacks off the dispatch thread
+def _callback_threads(job=None):
+    prefix = "callbacks-" if job is None else f"callbacks-{job}"
+    return [t for t in threading.enumerate()
+            if t.name.startswith(prefix) and t.is_alive()]
+
+
+def _blocking(entered: threading.Event, release: threading.Event, value):
+    """A .then transform that parks until ``release`` (a verifier stall)."""
+    def fn(res):
+        entered.set()
+        assert release.wait(timeout=30.0), "test never released the stall"
+        return value
+    return fn
+
+
+def test_one_jobs_callback_stall_is_filled_by_another_jobs_ops():
+    """Two jobs on one group under serve(): while job A's pack callback is
+    parked, job B's whole GENERATE -> pack -> UPDATE_ACTOR chain runs, and
+    A's update stays QUEUED behind its unresolved batch."""
+    r, _, trace = make_router(n_groups=0, duration=0.0)
+    a, b = (_deploy(r, dep_id=f"d{j}", job_id=j) for j in ("A", "B"))
+    entered, release = threading.Event(), threading.Event()
+    with r:
+        gen_a = a.generate([0], max_new_tokens=1)
+        upd_a = a.update_actor(gen_a.then(_blocking(entered, release, "a")))
+        assert entered.wait(timeout=10.0)
+        gen_b = b.generate([0], max_new_tokens=1)
+        upd_b = b.update_actor(gen_b.then(lambda res: "b"))
+        assert upd_b.wait(timeout=10.0)["req_id"] == upd_b.sources[0]
+        assert not upd_a.done()
+        assert r.executor.tasks[upd_a.sources[0]].state == State.QUEUED
+        release.set()
+        assert upd_a.wait(timeout=10.0)["req_id"] == upd_a.sources[0]
+    executed = [req for _, req, _, _ in trace]
+    assert executed.index(upd_b.sources[0]) < executed.index(upd_a.sources[0])
+    assert r.telemetry.snapshot()["dispatch.admitted_in_callbacks"] >= 1
+
+
+def test_op_waits_for_its_argument_future_not_only_its_source_op(
+        monkeypatch):
+    """An update whose GENERATE COMPLETED but whose batch future is still
+    being packed stays QUEUED (never RUNNING) and is admitted once the
+    batch resolves. The dispatch thread never waits on the future: with
+    the splice's bound at a microsecond, a wait would fail the op."""
+    monkeypatch.setattr(api, "SPLICE_TIMEOUT_S", 1e-6)
+    started = []
+
+    class StartLog(StubWPG):
+        def execute(self, qop):
+            started.append(qop.req_id)
+            return super().execute(qop)
+
+    trace = []
+    r = Router(wpg_factory=lambda spec, sm: StartLog(spec, sm, 0.0, trace))
+    dep = _deploy(r)
+    entered, release = threading.Event(), threading.Event()
+    with r:
+        gen = dep.generate([0], max_new_tokens=1)
+        batch = gen.then(_blocking(entered, release, {"packed": 1}))
+        upd = dep.update_actor(batch)
+        assert entered.wait(timeout=10.0)
+        (g,), (u,) = gen.sources, upd.sources
+        assert r.executor.tasks[g].state == State.COMPLETED
+        task = r.executor.tasks[u]
+        assert task.state == State.QUEUED and task.arg_gates == 1
+        assert r.executor.pick_next(0) is None
+        assert r.executor.pick_next_full(0) is None
+        assert u not in started
+        release.set()
+        assert upd.wait(timeout=10.0)["req_id"] == u
+    assert started == [g, u]
+    assert r.telemetry.snapshot()["dispatch.arg_gate_s"] > 0.0
+
+
+def test_errored_argument_future_fails_its_dependent_under_serve():
+    r, _, trace = make_router(n_groups=1, duration=0.0)
+    dep = api.Deployment(r.deployments["dep0"], r)
+    with r:
+        gen = dep.generate([0], max_new_tokens=1)
+        upd = dep.update_actor(gen.then(lambda res: 1 / 0))
+        tail = dep.forward(upd)
+        with pytest.raises(ZeroDivisionError):
+            upd.wait(timeout=10.0)
+        with pytest.raises(RuntimeError, match="prerequisite"):
+            tail.wait(timeout=10.0)
+        assert r.wait_idle(timeout=10.0)
+    assert [req for _, req, _, _ in trace] == list(gen.sources)
+
+
+def test_one_jobs_callbacks_fire_in_the_order_its_ops_finished():
+    """The first callback parks until every op has run, so the others
+    queue behind it on the job's callback worker; run_until_idle returns
+    only once all have fired."""
+    n = 5
+    all_ran = threading.Event()
+
+    class CountingStub(StubWPG):
+        def execute(self, qop):
+            out = super().execute(qop)
+            if len(self._trace) == n:
+                all_ran.set()
+            return out
+
+    trace = []
+    r = Router(wpg_factory=lambda spec, sm: CountingStub(spec, sm, 0.0,
+                                                         trace))
+    dep = _deploy(r)
+    fired = []
+    futs = [dep.forward(i) for i in range(n)]
+    futs[0].add_done_callback(
+        lambda f: (all_ran.wait(timeout=30.0), fired.append(f.sources[0])))
+    for f in futs[1:]:
+        f.add_done_callback(lambda f: fired.append(f.sources[0]))
+    assert r.run_until_idle(timeout=30.0) == n
+    assert fired == [req for _, req, _, _ in trace]
+    assert not _callback_threads()
+
+
+def test_drivers_stay_busy_while_a_resubmitting_callback_is_pending():
+    """run_until_idle and wait_idle must not see the plane idle while a
+    callback that will submit more work has yet to fire."""
+    r, _, _ = make_router(n_groups=1, duration=0.0)
+    dep = api.Deployment(r.deployments["dep0"], r)
+    go, follow = threading.Event(), []
+
+    def resubmit(f):
+        assert go.wait(timeout=30.0)
+        follow.append(dep.forward("follow-up"))
+
+    dep.forward(0).add_done_callback(resubmit)
+    worker = threading.Thread(target=lambda: follow.append(
+        r.run_until_idle(timeout=30.0)))
+    worker.start()
+    go.set()
+    worker.join(timeout=30.0)
+    assert not worker.is_alive()
+    assert follow[-1] == 2 and follow[0].done()
+
+    entered, release, follow = threading.Event(), threading.Event(), []
+
+    def parked_resubmit(f):
+        entered.set()
+        assert release.wait(timeout=30.0)
+        follow.append(dep.forward("follow-up"))
+
+    with r:
+        dep.forward(1).add_done_callback(parked_resubmit)
+        assert entered.wait(timeout=10.0)
+        assert not r.wait_idle(timeout=0.05)
+        release.set()
+        assert r.wait_idle(timeout=10.0)
+        assert follow[0].done()
+
+
+def test_callback_error_raises_at_shutdown():
+    r, _, _ = make_router(n_groups=1, duration=0.0)
+    dep = api.Deployment(r.deployments["dep0"], r)
+    r.serve()
+    f = dep.forward(0)
+    f.add_done_callback(lambda fut: 1 / 0)
+    assert f.wait(timeout=10.0)["req_id"] > 0
+    with pytest.raises(RuntimeError, match="callback"):
+        r.shutdown()
+    assert len(r.callback_errors) == 1
+    assert not _callback_threads()
+
+
+def test_no_callback_worker_survives_teardown_or_shutdown():
+    r, _, _ = make_router(n_groups=0, duration=0.0)
+    deps = {j: _deploy(r, dep_id=f"d{j}", job_id=j) for j in ("cbA", "cbB")}
+    with r:
+        for dep in deps.values():
+            assert dep.forward(0).wait(timeout=10.0)["req_id"] > 0
+        assert r.wait_idle(timeout=10.0)
+        assert len(_callback_threads("cbA")) == 1
+        r.teardown("dcbA")
+        assert not _callback_threads("cbA")
+        assert len(_callback_threads("cbB")) == 1
+    assert not _callback_threads()
+    assert r.drain() == 0             # serial again: nothing left behind

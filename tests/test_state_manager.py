@@ -1,6 +1,8 @@
 """StateManager: residency tiers, canonical dedup, materialisation, host
 optimizer, migration."""
 import os
+import sys
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -329,3 +331,53 @@ def test_every_host_consumer_gets_numpy(tmp_path):
     out = sm.gather("job", tree)
     for name in tree:
         np.testing.assert_array_equal(_bits(out[name]), _bits(tree[name]))
+
+
+def test_gather_sees_whole_trees_while_another_thread_moves_them(tmp_path):
+    """A client callback gathers a job's state while the dispatch thread
+    re-registers it (after an update) and switches it out and back in:
+    every gathered tree is whole, and one of the versions stored."""
+    sm = StateManager(disk_dir=str(tmp_path))
+    versions = [{f"w{i}": jnp.full((4, 4), float(v)) for i in range(16)}
+                for v in range(3)]
+    sm.register("job", versions[0])
+    errors, gathered = [], []
+    moving = threading.Event()
+
+    def mover():
+        try:
+            for i in range(150):
+                sm.reregister("job", versions[i % 3])
+                sm.register("other", versions[0])
+                sm.offload(sm.keys_for("job"), Tier.HOST)
+                sm.unregister(sm.keys_for("other"))
+                sm.prefetch(sm.keys_for("job"))
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+        finally:
+            moving.set()
+
+    def reader():
+        try:
+            while not moving.is_set():
+                out = sm.gather("job", versions[0])
+                vals = {float(np.asarray(x)[0, 0]) for x in out.values()}
+                gathered.append(vals)
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=f) for f in (mover, reader)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120.0)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert gathered
+    # every leaf of a gathered tree came from the same stored version
+    assert all(len(vals) == 1 for vals in gathered), gathered
