@@ -6,11 +6,7 @@ concurrency gain + per-op control overhead (serial driver vs
 Router.run_until_idle), the serve-mode submit->admission latency on an idle
 persistent plane, the control plane's placement costs: cold/warm fit
 decision latency vs resident-job count, the wall-clock of a realized
-repack migration (hold -> drain -> StateManager.migrate -> rehome), and
-the process plane's costs: IPC dispatch round-trip through a group worker
-process vs the in-process call, and 2-group compute-bound overlap in both
-dispatch modes (threads GIL-bound near 1.0x serialized; processes overlap
-wherever cores exist).
+repack migration (hold -> drain -> StateManager.migrate -> rehome).
 """
 from __future__ import annotations
 
@@ -86,60 +82,6 @@ def _dispatch_wall(n_groups: int, ops_per_group: int, duration: float,
     else:
         router.drain()
     return time.perf_counter() - t0
-
-
-def _proc_roundtrip_us(iters: int = 200) -> float:
-    """IPC dispatch overhead of the process plane: one zero-cost op through
-    ``WPGProxy.execute`` — payload pickle, pipe write, child dispatch,
-    reply pickle, log-mirror append — measured directly against the proxy
-    (no admission path), the apples-to-apples counterpart of the in-process
-    ``dispatch/op_overhead_us`` row (~65 us)."""
-    router = Router(process_plane=True,
-                    proc_wpg_factory="repro.launch.stub_wpg:make_busy_wpg")
-    spec = api.DeploymentSpec(deployment_id="dep0", job_id="job0",
-                              model_name="stub", role="train")
-    try:
-        wpg = router.create_deployment(spec, group_id=0)
-        qop = api.make_op(spec, api.Op.FORWARD, 0)
-        wpg.execute(qop)                       # warm: spawn + handshake
-        return _time_us(lambda: wpg.execute(qop), iters=iters)
-    finally:
-        router.close_processes()
-
-
-def _compute_overlap_wall(n_groups: int, ops_per_group: int, busy_s: float,
-                          process_plane: bool) -> float:
-    """Wall-clock of a COMPUTE-BOUND 2-group workload (GIL-holding spin per
-    op, burning thread CPU time) in either dispatch mode. Children are
-    warmed with one zero-cost op each so spawn/handshake stays outside the
-    timed region."""
-    if process_plane:
-        router = Router(process_plane=True,
-                        proc_wpg_factory="repro.launch.stub_wpg:make_busy_wpg")
-    else:
-        from repro.launch.stub_wpg import make_busy_wpg
-        router = Router(wpg_factory=make_busy_wpg)
-    try:
-        specs = []
-        for g in range(n_groups):
-            spec = api.DeploymentSpec(deployment_id=f"dep{g}",
-                                      job_id=f"job{g}", model_name="stub",
-                                      role="train")
-            router.create_deployment(spec, group_id=g)
-            specs.append(spec)
-        for spec in specs:
-            router.submit_queued_operation(api.make_op(spec, api.Op.FORWARD, 0))
-        router.run_until_idle(timeout=60.0)
-        t0 = time.perf_counter()
-        for spec in specs:
-            for i in range(ops_per_group):
-                router.submit_queued_operation(
-                    api.make_op(spec, api.Op.FORWARD, i, busy_s=busy_s))
-        router.run_until_idle(timeout=60.0)
-        return time.perf_counter() - t0
-    finally:
-        if process_plane:
-            router.close_processes()
 
 
 def _serve_attach_latency_us(iters: int = 300) -> float:
@@ -463,34 +405,17 @@ def run() -> list[tuple[str, float, str]]:
     rows.append(("dispatch/serve_attach_latency_us",
                  _serve_attach_latency_us(),
                  "median, idle serve() plane"))
-    # process plane: IPC round-trip cost of one dispatched op (vs the
-    # ~65us in-process op_overhead_us above), and the 2-group COMPUTE-bound
-    # overlap in both modes — threads hold the GIL through the spin so they
-    # serialize near 1.0x; worker processes overlap for real wherever >= 2
-    # cores exist (the ratio is reported against the serialized cost)
-    import os as _os
-    rows.append(("dispatch/proc_roundtrip_us", _proc_roundtrip_us(),
-                 "WPGProxy.execute, zero-cost op, vs in-process "
-                 "op_overhead_us"))
-    n_groups, ops, busy = 2, 3, 0.06
-    serial_s = n_groups * ops * busy
-    cores = len(_os.sched_getaffinity(0))
-    w_thr = _compute_overlap_wall(n_groups, ops, busy, process_plane=False)
-    w_proc = _compute_overlap_wall(n_groups, ops, busy, process_plane=True)
-    rows.append(("dispatch/compute_overlap_threads_x",
-                 w_thr / serial_s,
-                 f"wall/serial, {n_groups}x{ops}x{busy * 1e3:.0f}ms spin, "
-                 f"{cores} cores (GIL-bound ~1.0)"))
-    rows.append(("dispatch/compute_overlap_procs_x",
-                 w_proc / serial_s,
-                 f"wall/serial, process plane, {cores} cores "
-                 f"(<=0.6 with >=2 cores)"))
     return rows
 
 
 if __name__ == "__main__":
     import argparse
+    import os
+    import sys
 
+    # run as a script, the checkout root is not on the path
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from benchmarks.run import BENCH_JSON, write_bench_json
 
     ap = argparse.ArgumentParser()

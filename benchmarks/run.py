@@ -40,7 +40,6 @@ def main() -> None:
     import benchmarks.hrrs_bench as hrrsb
     import benchmarks.mesh_bench as meshb
     import benchmarks.roofline as roofline
-    import benchmarks.transport_bench as transportb
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=BENCH_JSON,
@@ -56,7 +55,6 @@ def main() -> None:
         ("hrrs_bench", hrrsb),
         ("mesh_bench", meshb),
         ("roofline", roofline),
-        ("transport_bench", transportb),
     ]
     print("name,value,derived")
     failed = []

@@ -48,14 +48,10 @@ class PlexCluster:
     def __init__(self, n_groups: int = 1, policy: str = "hrrs",
                  wpg_factory=None,
                  director_cfg: Optional[DirectorConfig] = None,
-                 devices_per_group: Optional[int] = None,
-                 process_plane: bool = False,
-                 proc_wpg_factory: Optional[str] = None):
+                 devices_per_group: Optional[int] = None):
         kwargs = {} if wpg_factory is None else {"wpg_factory": wpg_factory}
         self.router = Router(policy=policy,
-                             devices_per_group=devices_per_group,
-                             process_plane=process_plane,
-                             proc_wpg_factory=proc_wpg_factory, **kwargs)
+                             devices_per_group=devices_per_group, **kwargs)
         self.controllers: Dict[str, _RLControllerBase] = {}
         self.billing: Dict[str, BillingRecord] = {}
         # incremental billing cursors: exec-log offset per deployment and

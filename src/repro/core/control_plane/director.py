@@ -736,16 +736,7 @@ class PlacementDirector:
         """Trigger 3 + §4.4 capacity adjustment: a deep-queued group sheds
         its worst-interfering warm job onto another group; when nothing is
         sheddable a spare group is kept available; with no pressure, idle
-        groups retire.
-
-        Process plane: a dead group worker process is respawned first (the
-        capacity adjuster IS the plane's supervisor — a crashed group is a
-        capacity loss exactly like a failed node). Thread mode returns no
-        dead groups, so replay determinism is untouched."""
-        respawn = getattr(self.router, "respawn_dead_groups", None)
-        if respawn is not None:
-            for gid in respawn():
-                self._log("respawn_group", group=gid, t=now)
+        groups retire."""
         telem = self.router.group_telemetry()
         deep = sorted(g for g, t in telem.items()
                       if t["queue_depth"] >= self.cfg.spawn_queue_depth)

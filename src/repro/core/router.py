@@ -52,7 +52,6 @@ error, and any queued operation whose prerequisite FAILED is itself failed
 """
 from __future__ import annotations
 
-import dataclasses
 import logging
 import queue
 import threading
@@ -64,9 +63,7 @@ from repro.core.scheduler import hrrs
 from repro.core.scheduler.executor import State, Task, TaskExecutor
 from repro.core.state_manager import StateManager, Tier
 from repro.core.worker import WorkerProcessGroup
-from repro.launch.mesh import DevicePlane, env_for_slice
-from repro.launch.proc_plane import (GroupProcess, StateManagerProxy,
-                                     WPGProxy)
+from repro.launch.mesh import DevicePlane
 
 logger = logging.getLogger(__name__)
 
@@ -100,49 +97,14 @@ class Router:
                  policy: str = "hrrs",
                  wpg_factory: Callable[..., object] = WorkerProcessGroup,
                  device_plane: Optional[DevicePlane] = None,
-                 devices_per_group: Optional[int] = None,
-                 process_plane: bool = False,
-                 proc_wpg_factory: Optional[str] = None,
-                 shm_transport: Optional[bool] = None,
-                 shm_threshold: Optional[int] = None):
-        """``process_plane=True`` hosts each node group's WPGs in a separate
-        OS process bound to the group's mesh slice (launch/proc_plane.py):
-        dispatch crosses an IPC pipe instead of a method call, so groups on
-        disjoint slices overlap as real OS-level parallelism instead of
-        GIL-bound threads. In-process mode (the default) is bit-identical
-        to the pre-process-plane plane — including VirtualClock replay.
-        ``proc_wpg_factory`` names the child-side factory as
-        "module:callable" (factories cross the spawn boundary by name, not
-        pickle); None means the real WorkerProcessGroup.
-
-        ``shm_transport`` controls the process plane's zero-copy
-        shared-memory array transport (launch/shm_transport.py): None
-        auto-enables it where the host supports it, False forces the
-        pickle path. ``shm_threshold`` overrides the per-array size above
-        which arrays ride shm (default: the measured crossover).
-
-        The process plane is CPU-only: an accelerator chip belongs to one
-        process, and this parent already holds every chip it can see. On
-        an accelerator, the in-process plane drives all chips (one mesh
-        slice per group) from this one process."""
+                 devices_per_group: Optional[int] = None):
+        """Every node group's WPGs live in this process and drive its chips
+        through the group's mesh slice: one process drives all chips."""
         # the device plane leases each group a disjoint mesh slice; on one
         # default device every group shares the lone slice (legacy view)
         self.device_plane = device_plane or DevicePlane(
             slice_size=devices_per_group)
-        if process_plane:
-            platforms = {d.platform for d in self.device_plane.devices()}
-            if platforms != {"cpu"}:
-                raise ValueError(
-                    f"process_plane=True needs CPU devices, found "
-                    f"{sorted(platforms)}: a chip belongs to one process. "
-                    "Use the in-process plane (process_plane=False), which "
-                    "drives every chip from this process.")
         self.now = now
-        self.process_plane = process_plane
-        self.proc_wpg_factory = proc_wpg_factory
-        self.shm_transport = shm_transport
-        self.shm_threshold = shm_threshold
-        self.group_procs: Dict[int, GroupProcess] = {}
         # dispatch workers hung inside wpg.execute past their abandon grace
         # (daemon threads we can't kill) — reported, never silently dropped
         self._abandoned: List[threading.Thread] = []
@@ -194,34 +156,17 @@ class Router:
         """The group's StateManager, creating it (and leasing the group's
         mesh slice from the device plane) on first sight. The slice lease
         is what gives the group hardware affinity: every WPG on the group
-        reads ``sm.mesh_slice`` for its jit/sharding mesh. In process mode
-        first sight also SPAWNS the group's worker process (launch returns
-        immediately; the ready handshake is awaited on first use) and the
-        returned object is a :class:`StateManagerProxy` over its pipe."""
+        reads ``sm.mesh_slice`` for its jit/sharding mesh."""
         sm = self.state_managers.get(group_id)
         if sm is None:
             sl = self.device_plane.slice_for_group(group_id)
-            if self.process_plane:
-                sm = self._spawn_group_process(group_id, sl)
-            else:
-                sm = StateManager(node_id=f"group{group_id}",
-                                  clock=self.now, mesh_slice=sl,
-                                  telemetry=self.telemetry)
+            sm = StateManager(node_id=f"group{group_id}",
+                              clock=self.now, mesh_slice=sl,
+                              telemetry=self.telemetry)
             self.state_managers[group_id] = sm
         elif sm.mesh_slice is None:
             sm.mesh_slice = self.device_plane.slice_for_group(group_id)
         return sm
-
-    def _spawn_group_process(self, group_id: int, sl) -> StateManagerProxy:
-        gp = GroupProcess(group_id, env=env_for_slice(sl),
-                          slice_index=sl.index,
-                          wpg_factory=self.proc_wpg_factory,
-                          node_id=f"group{group_id}",
-                          shm=self.shm_transport,
-                          shm_threshold=self.shm_threshold)
-        self.group_procs[group_id] = gp
-        return StateManagerProxy(gp, mesh_slice=sl,
-                                 node_id=f"group{group_id}")
 
     def mesh_domains(self) -> Dict[int, int]:
         """group id -> mesh-slice index (the placement layer's domain map:
@@ -233,17 +178,12 @@ class Router:
         """Register a deployment (low level; returns the WPG). While serving,
         a deployment on a group without a dispatch worker spawns one, so
         jobs attach to a live plane without a restart."""
-        if state_manager is not None and self.process_plane:
-            raise RuntimeError("explicit state_manager is incompatible with "
-                               "process_plane (state lives in the group's "
-                               "worker process)")
         with self.executor.cv:
             sm = state_manager or self._group_sm(group_id)
             self.state_managers[group_id] = sm
-        # built OUTSIDE the cv: a slow model build (or the child process's
-        # create_deployment round trip) must not stall the dispatch plane
-        wpg = WPGProxy(spec, sm) if self.process_plane \
-            else self.wpg_factory(spec, sm)
+        # built OUTSIDE the cv: a slow model build must not stall the
+        # dispatch plane
+        wpg = self.wpg_factory(spec, sm)
         with self.executor.cv:
             self.wpgs[spec.deployment_id] = wpg
             self.deployments[spec.deployment_id] = spec
@@ -319,9 +259,6 @@ class Router:
                                     for q in self.pending.values()),
                     timeout=120.0)
             wpg.sm.unregister(wpg.sm.keys_for(wpg.job_prefix))
-            close = getattr(wpg, "close", None)
-            if close is not None:       # process mode: drop the child-side WPG
-                close()
         if cancelled:
             try:
                 for qop, err in cancelled:
@@ -822,39 +759,6 @@ class Router:
             self._abandoned = [t for t in self._abandoned if t.is_alive()]
             return [t.name for t in self._abandoned]
 
-    # ------------------------------------------------------- process plane
-    def process_health(self) -> Dict[int, bool]:
-        """group id -> worker-process liveness (process mode; empty dict in
-        thread mode)."""
-        return {gid: gp.alive() for gid, gp in self.group_procs.items()}
-
-    def respawn_dead_groups(self) -> List[int]:
-        """Respawn every dead group worker process in place (deployments
-        replayed; managed state lost — device-failure semantics). Called by
-        the capacity adjuster each poll; returns the respawned group ids.
-        A no-op in thread mode, so VirtualClock replay never sees it.
-        Each respawn first reaps the dead incarnation's in-flight shm
-        segments (by name prefix) and sweeps its orphaned ``export__*``
-        migration spill files, so a crash-looping group never accretes
-        ``/dev/shm`` or ``/tmp`` residue (see ``GroupProcess.respawn``)."""
-        respawned: List[int] = []
-        for gid, gp in list(self.group_procs.items()):
-            if not gp.alive():
-                logger.warning("group %d worker process died (exitcode %s); "
-                               "respawning", gid,
-                               None if gp._proc is None else gp._proc.exitcode)
-                gp.respawn()
-                respawned.append(gid)
-        return respawned
-
-    def close_processes(self, timeout: float = 10.0):
-        """Shut down every group worker process (graceful protocol shutdown,
-        escalating to terminate). Benches/tests call this at exit; children
-        are daemons, so an unclosed plane still dies with the parent."""
-        for gp in self.group_procs.values():
-            gp.shutdown(timeout=timeout)
-        self.group_procs.clear()
-
     # ------------------------------------------- group lifecycle / telemetry
     def known_groups(self) -> List[int]:
         with self.executor.cv:
@@ -909,7 +813,6 @@ class Router:
             if self._serving:
                 self._ensure_serve_worker(group_id)
             raise
-        gp = None
         with ex.cv:
             # re-check under the lock: an attach that raced past drop_group
             # owns the group again — leave its (empty) StateManager alone
@@ -919,9 +822,6 @@ class Router:
                     del self.state_managers[group_id]
                     # return the group's mesh-slice lease to the plane
                     self.device_plane.release(group_id)
-                    gp = self.group_procs.pop(group_id, None)
-        if gp is not None:          # outside the cv: shutdown joins the child
-            gp.shutdown()
 
     def group_telemetry(self) -> Dict[int, dict]:
         """Per-group queue-depth / occupancy snapshot (the §4.4 capacity
@@ -944,10 +844,6 @@ class Router:
                         d for d, gg in self.group_of.items() if gg == g),
                     "worker": g in self._serve_threads,
                 }
-        if self.process_plane:
-            for g, d in out.items():
-                gp = self.group_procs.get(g)
-                d["process_alive"] = bool(gp is not None and gp.alive())
         return out
 
     def tenant_telemetry(self) -> Dict[str, dict]:

@@ -521,125 +521,6 @@ class StateManager:
                              "cross_mesh": cross_mesh, "keys": len(keys)}
         return moved
 
-    # ------------------------------------ cross-PROCESS migration halves
-    def export_state(self, job_id: str, max_inline_bytes: int = 64 << 20
-                     ) -> Dict[str, Any]:
-        """Serialise a job's managed state for transport to ANOTHER PROCESS
-        (the process plane's migrate-export). Everything is host-staged —
-        jax arrays cannot cross a pipe — and entries larger than
-        ``max_inline_bytes`` spill to a fresh disk-tier file and travel by
-        absolute path instead (same host, so the importer reads it
-        directly). With the shm transport active the caller disables this
-        tier (``max_inline_bytes`` huge): inline arrays ride shared-memory
-        descriptors instead, which beats the double disk pass. bf16
-        travels as uint16 views (numpy pickles those; ml_dtypes scalars it
-        may not), PartitionSpecs as plain tuples. Non-destructive: the
-        source keeps its entries until the importer has committed and the
-        caller drops them.
-
-        Spill files are TRANSACTION-SCOPED: names carry a fresh transfer
-        id (``export__{txn}__...``), the payload lists them under
-        ``"spills"``, and exactly one party deletes them — the importer on
-        commit AND on rollback (:meth:`import_state`), or the caller when
-        the importer died before running (``StateManagerProxy.migrate``);
-        ``respawn_dead_groups`` sweeps anything a crash orphaned."""
-        import uuid
-        keys = list(self.keys_for(job_id))
-        txn = uuid.uuid4().hex[:12]
-        entries = []
-        spills: List[str] = []
-        total = 0
-        t0 = self.clock()
-        for k in keys:
-            e = self.entries[k]
-            if e.tier == Tier.DISK:
-                arr = np.load(e.path)
-                if e.is_bf16:
-                    arr = arr.view(jnp.bfloat16)
-            elif e.tier == Tier.DEVICE:
-                arr = np.asarray(jax.device_get(e.ref))
-            else:
-                arr = np.asarray(e.ref)
-            is_bf16 = arr.dtype == jnp.bfloat16
-            ent = {"key": k, "nbytes": e.nbytes, "version": e.version,
-                   "tier": int(e.tier), "is_bf16": is_bf16,
-                   "spec": None if e.spec is None else tuple(e.spec),
-                   "path": None, "data": None}
-            wire = arr.view(np.uint16) if is_bf16 else arr
-            if arr.nbytes > max_inline_bytes:
-                os.makedirs(self.disk_dir, exist_ok=True)
-                path = os.path.join(
-                    self.disk_dir,
-                    f"export__{txn}__" + k.replace("/", "__") + ".npy")
-                np.save(path, wire)
-                ent["path"] = path
-                spills.append(path)
-            else:
-                ent["data"] = wire
-            entries.append(ent)
-            total += e.nbytes
-        self._record("migrate", total, self.clock() - t0)
-        return {"job_id": job_id, "entries": entries, "bytes": total,
-                "txn": txn, "spills": spills}
-
-    def import_state(self, payload: Dict[str, Any]) -> int:
-        """Adopt an :meth:`export_state` payload into THIS manager.
-        Entries exported from DEVICE re-lay-out onto this manager's mesh
-        slice with their recorded spec; HOST/DISK exports arrive HOST.
-        Transactional like :meth:`migrate`: a mid-import failure removes
-        every staged entry before re-raising, leaving the (untouched)
-        exporter the sole owner. The transaction also owns the exporter's
-        spill files: consumed (unlinked) on success AND on rollback —
-        either way the transfer is over and nobody will read them again."""
-        t0 = self.clock()
-        staged: List[str] = []
-        spills = [p for p in payload.get("spills", ())
-                  if p and os.path.basename(p).startswith("export__")]
-        moved = 0
-        try:
-            for ent in payload["entries"]:
-                if ent["path"] is not None:
-                    arr = np.load(ent["path"])
-                else:
-                    arr = ent["data"]
-                if ent["is_bf16"]:
-                    arr = arr.view(jnp.bfloat16)
-                spec = None if ent["spec"] is None \
-                    else PartitionSpec(*ent["spec"])
-                if Tier(ent["tier"]) == Tier.DEVICE:
-                    ref = self._to_device(arr, spec)
-                    tier, spec = Tier.DEVICE, self._leaf_spec(ref)
-                else:
-                    # HOST entries must own their buffer: ``arr`` may be a
-                    # view over a pooled shm segment that is recycled the
-                    # moment this import's reply acks the transfer
-                    arr = np.asarray(arr)
-                    ref = arr if arr.base is None else np.array(arr)
-                    tier = Tier.HOST
-                self.entries[ent["key"]] = Entry(
-                    key=ent["key"], tier=tier, nbytes=ent["nbytes"],
-                    ref=ref, version=ent["version"],
-                    last_touch=self.clock(), spec=spec)
-                staged.append(ent["key"])
-                moved += ent["nbytes"]
-        except Exception:
-            for k in staged:     # rollback: the exporter still owns the state
-                self.entries.pop(k, None)
-            for path in spills:  # transfer dead — spills will never be read
-                if os.path.exists(path):
-                    os.unlink(path)
-            raise
-        for path in spills:
-            if os.path.exists(path):
-                os.unlink(path)
-        self._evict_if_needed()
-        dt = self.clock() - t0
-        self._record("load", moved, dt)
-        self.last_migrate = {"bytes": moved, "seconds": dt,
-                             "cross_mesh": True,
-                             "keys": len(payload["entries"])}
-        return moved
-
     # ------------------------------------------- §4.5.4 host optimizer
     def host_optimizer_step(self, job_id: str, grads_tree, template,
                             lr: float = 3e-5, b1: float = 0.9,

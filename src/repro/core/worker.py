@@ -15,9 +15,8 @@ destination slice.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -169,14 +168,6 @@ class WorkerProcessGroup:
     def params(self):
         return self.sm.gather(self.job_prefix, self._params_template(),
                               "params")
-
-    def host_params(self):
-        """Params gathered to host numpy — the process plane's cross-process
-        weight-sync export (the tree must pickle across the group pipe, so
-        no jax.Array leaves may remain)."""
-        import numpy as np
-        return jax.tree.map(lambda a: np.asarray(jax.device_get(a)),
-                            self.params())
 
     def opt_state(self) -> opt.AdamWState:
         tmpl = opt.abstract_state(self._params_template(), self.adamw_cfg)

@@ -27,7 +27,6 @@ contract is untouched.
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -83,33 +82,6 @@ class MeshSlice:
 
     def device_ids(self) -> Tuple[int, ...]:
         return tuple(d.id for d in self.devices)
-
-
-def env_for_slice(sl: MeshSlice) -> Dict[str, str]:
-    """The child-process environment for a spawned group process on the
-    CPU backend: a virtual-device world of the slice's size (slice identity
-    is positional there — fine, since CPU devices are fungible). Must be
-    applied in the child before jax's backend initialises — see
-    launch/proc_plane.py. An accelerator belongs to one process, so the
-    process plane is CPU-only (``Router`` refuses it elsewhere);
-    accelerator slices are driven in-process."""
-    return {"JAX_PLATFORMS": "cpu",
-            "XLA_FLAGS":
-                f"--xla_force_host_platform_device_count={sl.n_devices}"}
-
-
-def host_shm_bytes(path: str = "/dev/shm") -> Optional[int]:
-    """Free bytes in the host's POSIX shared-memory filesystem, or ``None``
-    where it doesn't exist (macOS, some containers). The process plane's
-    shm transport sizes its segment pools against this: tmpfs defaults to
-    half of RAM, and a container run with a small ``--shm-size`` will make
-    ``shm_transport.shm_available()`` fall back to pipe pickling rather
-    than fail mid-transfer."""
-    try:
-        st = os.statvfs(path)
-    except OSError:
-        return None
-    return st.f_bavail * st.f_frsize
 
 
 class DevicePlane:

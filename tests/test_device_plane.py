@@ -13,7 +13,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import api
 from repro.core.cluster import BillingRecord, PlexCluster
 from repro.core.controller import JobConfig
-from repro.core.router import Router
 from repro.core.state_manager import StateManager, Tier, _spec_divides
 from repro.core.worker import ExecLog
 from repro.launch import compile_cache
@@ -78,14 +77,6 @@ def test_make_local_mesh_validates_device_count():
     msg = str(ei.value)
     assert "xla_force_host_platform_device_count" in msg
     assert str(N_DEV + 1) in msg
-
-
-def test_process_plane_refuses_accelerator_devices():
-    """A chip belongs to one process: a Router whose parent sees
-    accelerator devices must not spawn per-group children."""
-    tpu = types.SimpleNamespace(platform="tpu", id=0)
-    with pytest.raises(ValueError, match="in-process plane"):
-        Router(process_plane=True, device_plane=DevicePlane(devices=[tpu]))
 
 
 def test_hw_peaks_keyed_by_device_kind():

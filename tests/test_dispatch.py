@@ -17,12 +17,14 @@ import threading
 import time
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
 from repro.core import api
 from repro.core.cluster import BillingRecord, PlexCluster
 from repro.core.router import Router
 from repro.core.scheduler.executor import State, VirtualClock
+from repro.core.state_manager import StateManager
 
 
 class StubWPG:
@@ -255,6 +257,76 @@ def test_serial_driver_also_poisons_dependents():
     with pytest.raises(RuntimeError, match="prerequisite"):
         f_dep.result()
     assert not r.pending
+
+
+@pytest.mark.parametrize("driver", ["serial", "run_until_idle", "serve"])
+def test_group_serves_on_after_a_failure_poisons_its_dependents(driver):
+    """An op that raises fails its dependents, not its group: an
+    independent op on the same group then completes, and only it is
+    billed."""
+    r, specs, _ = make_router(n_groups=1, duration=0.0)
+    spec = specs[0]
+    wpg = r.wpgs[spec.deployment_id]
+
+    def drive():
+        if driver == "serial":
+            r.drain()
+        elif driver == "run_until_idle":
+            r.run_until_idle(timeout=30.0)
+        else:
+            assert r.wait_idle(timeout=30.0)
+
+    if driver == "serve":
+        r.serve()
+    try:
+        bad = api.make_op(spec, api.Op.FORWARD, 0, fail=True)
+        dep = api.make_op(spec, api.Op.FORWARD, 1,
+                          prerequisites=(bad.req_id,))
+        f_bad = r.submit_queued_operation(bad)
+        f_dep = r.submit_queued_operation(dep)
+        drive()
+        with pytest.raises(RuntimeError, match="injected"):
+            f_bad.wait(timeout=5.0)
+        with pytest.raises(RuntimeError, match="prerequisite"):
+            f_dep.wait(timeout=5.0)
+        assert wpg.exec_log == []
+        ok = api.make_op(spec, api.Op.FORWARD, 2)
+        f_ok = r.submit_queued_operation(ok)
+        drive()
+        assert f_ok.wait(timeout=5.0) == {"req_id": ok.req_id}
+        assert [op for op, _ in wpg.exec_log] == ["forward"]
+        assert r.executor.tasks[ok.req_id].state == State.COMPLETED
+        assert not r.pending
+    finally:
+        r.shutdown()
+
+
+def test_create_deployment_adopts_an_explicit_state_manager():
+    """A caller's StateManager becomes the group's, and the WPG is built
+    against it."""
+    r = Router(wpg_factory=lambda spec, sm: StubWPG(spec, sm, 0.0, []))
+    sm = StateManager(node_id="mine")
+    spec = api.DeploymentSpec(deployment_id="d", job_id="j",
+                              model_name="stub", role="train")
+    wpg = r.create_deployment(spec, group_id=3, state_manager=sm)
+    assert wpg.sm is sm and r.state_managers[3] is sm
+    assert r.group_of["d"] == 3 and r.wpgs["d"] is wpg
+
+
+def test_teardown_drops_only_the_departing_deployments_state():
+    r = Router(wpg_factory=lambda spec, sm: StubWPG(spec, sm, 0.0, []))
+    wpgs = {}
+    for job in ("stay", "go"):
+        spec = api.DeploymentSpec(deployment_id=f"{job}-train", job_id=job,
+                                  model_name="stub", role="train")
+        wpgs[job] = r.create_deployment(spec, group_id=0)
+        wpgs[job].sm.register(wpgs[job].job_prefix,
+                              {"w": np.ones((4, 4), np.float32)})
+    sm = r.state_managers[0]
+    r.teardown("go-train")
+    assert not sm.keys_for(wpgs["go"].job_prefix)
+    assert sm.keys_for(wpgs["stay"].job_prefix)
+    assert "go-train" not in r.wpgs and "go-train" not in r.group_of
 
 
 @pytest.mark.parametrize("driver", ["serial", "concurrent"])

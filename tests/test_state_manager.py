@@ -12,6 +12,7 @@ import pytest
 from repro.core import state_manager as sm_lib
 from repro.core.state_manager import StateManager, Tier
 from repro.core.telemetry import Counters
+from repro.launch.mesh import DevicePlane
 from repro.train import optimizer as opt
 
 
@@ -137,6 +138,32 @@ def _mixed_tree():
 def _bits(x):
     x = np.asarray(x)
     return x.view(np.uint16) if x.dtype == jnp.bfloat16 else x
+
+
+@pytest.mark.parametrize("tier,lands", [(Tier.DEVICE, Tier.DEVICE),
+                                        (Tier.HOST, Tier.HOST),
+                                        (Tier.DISK, Tier.HOST)],
+                         ids=lambda t: t.name)
+def test_migrate_keeps_each_entrys_bits_and_tier(tmp_path, tier, lands):
+    """Onto a destination with a mesh slice, a DEVICE entry lands on the
+    device and a HOST entry on the host; a DISK entry is read back and
+    lands on the host. Every entry keeps its bits."""
+    sl = DevicePlane().slice_for_group(0)
+    src = StateManager(node_id="src", disk_dir=str(tmp_path / "src"),
+                       mesh_slice=sl)
+    dst = StateManager(node_id="dst", disk_dir=str(tmp_path / "dst"),
+                       mesh_slice=sl)
+    tree = _mixed_tree()
+    keys = src.register("job", tree)
+    if tier != Tier.DEVICE:
+        src.offload(keys, tier)
+    src.migrate("job", dst)
+    assert not src.keys_for("job")
+    assert {dst.entries[k].tier for k in keys} == {lands}
+    out = dst.gather("job", tree)
+    for name in tree:
+        assert np.asarray(out[name]).dtype == tree[name].dtype
+        np.testing.assert_array_equal(_bits(out[name]), _bits(tree[name]))
 
 
 def _spy_to_host(monkeypatch, before=lambda: None):
@@ -289,8 +316,8 @@ def test_pinned_buffers_go_with_the_jobs_last_entry(tmp_path):
 
 def test_every_host_consumer_gets_numpy(tmp_path):
     """Offloaded HOST entries hold pinned-host jax arrays; gather,
-    export_state, migrate, host_optimizer_step and the DISK path all see
-    numpy with the offloaded bits."""
+    migrate, host_optimizer_step and the DISK path all see numpy with the
+    offloaded bits."""
     tree = _mixed_tree()
     params = {"w": tree["w"], "mu": tree["mu"]}
 
@@ -306,10 +333,6 @@ def test_every_host_consumer_gets_numpy(tmp_path):
     for name in tree:
         assert type(out[name]) is np.ndarray
         np.testing.assert_array_equal(_bits(out[name]), _bits(tree[name]))
-
-    sm, keys = offloaded("export")
-    for ent in sm.export_state("job")["entries"]:
-        assert type(ent["data"]) is np.ndarray
 
     sm, keys = offloaded("migrate")
     dst = StateManager(node_id="dst", disk_dir=str(tmp_path / "dst"))

@@ -1,6 +1,7 @@
 """End-to-end system behaviour: the full PlexRL stack (Router + HRRS
 executor + StateManager + WPGs + RLController) running real model execution
 on CPU, including context switching, fault tolerance, and migration."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -98,7 +99,11 @@ def test_migration_between_groups():
     assert params["embed"]["embedding"].shape[0] == 64
 
 
-def test_weight_sync_between_deployments():
+@pytest.mark.parametrize("rollout_group", [0, 1])
+def test_weight_sync_between_deployments(rollout_group):
+    """Trained weights land bit-identically in a rollout deployment on the
+    trainer's group or on another one, laid out with the rollout group's
+    own shardings."""
     c = PlexCluster(n_groups=1)
     ctl = c.add_job(_job("jobE", 5, steps=1))
     c.run()
@@ -106,19 +111,22 @@ def test_weight_sync_between_deployments():
     spec = api.DeploymentSpec(deployment_id="jobE-rollout", job_id="jobE",
                               model_name="qwen2-0.5b", role="rollout",
                               overrides=TINY)
-    rollout_wpg = c.router.create_deployment(spec, group_id=0)
+    rollout_wpg = c.router.create_deployment(spec, group_id=rollout_group)
+    assert rollout_wpg.sm is c.router.state_managers[rollout_group]
     train_wpg = c.router.wpgs["jobE-train"]
     res = train_wpg._op_sync_weights(rollout_wpg)
     assert res["synced_bytes"] > 0
-    a = train_wpg.params()["embed"]["embedding"]
-    b = rollout_wpg.params()["embed"]["embedding"]
-    np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                  np.asarray(b, np.float32))
+    for x, y, want in zip(jax.tree.leaves(train_wpg.params()),
+                          jax.tree.leaves(rollout_wpg.params()),
+                          jax.tree.leaves(rollout_wpg.param_shardings()),
+                          strict=True):
+        np.testing.assert_array_equal(np.asarray(x).view(np.uint8),
+                                      np.asarray(y).view(np.uint8))
+        assert y.sharding == want
 
 
 def test_host_optimizer_offload_path():
     """ZeRO-offload: grads computed on device, optimizer step on host state."""
-    import jax
     from repro.configs import ShapeSpec
     c = PlexCluster(n_groups=1)
     c.add_job(_job("jobF", 6, steps=1))
